@@ -45,7 +45,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import numpy as np, jax, jax.numpy as jnp, json
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro.compat import make_mesh
+from repro.launch.mesh import make_mesh
 from repro.core.sharding import TableSpec
 from repro.core.embedding import DisaggEmbedding
 from repro.launch.hlo_analysis import analyze
@@ -90,22 +90,27 @@ def run(batch: int = 1024, seed: int = 0) -> dict:
 
     env = dict(os.environ)
     env["PYTHONPATH"] = str(pathlib.Path(__file__).resolve().parents[1] / "src")
+    # A count over virtual CPU devices: the child never reaches for an
+    # accelerator, which this process may already hold.
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run(
         [sys.executable, "-c", SPMD_PROBE], env=env, capture_output=True,
         text=True, timeout=560,
     )
-    spmd = json.loads(proc.stdout.strip().splitlines()[-1]) if proc.returncode == 0 else {}
-    out = {
+    if proc.returncode != 0:
+        raise RuntimeError(
+            f"SPMD probe child exited {proc.returncode}:\n{proc.stderr}"
+        )
+    spmd = json.loads(proc.stdout.strip().splitlines()[-1])
+    return {
         "us_per_call": 1e6 * (time.perf_counter() - t0),
         "host_raw_bytes": raw,
         "host_pushdown_bytes": pd,
         "host_reduction": raw / max(pd, 1),
+        "spmd_baseline_coll_bytes": spmd["baseline"],
+        "spmd_hierarchical_coll_bytes": spmd["hierarchical"],
+        "spmd_reduction": spmd["baseline"] / max(spmd["hierarchical"], 1),
     }
-    if spmd:
-        out["spmd_baseline_coll_bytes"] = spmd["baseline"]
-        out["spmd_hierarchical_coll_bytes"] = spmd["hierarchical"]
-        out["spmd_reduction"] = spmd["baseline"] / max(spmd["hierarchical"], 1)
-    return out
 
 
 def _replay(tables, tnp, stream, segments: bool, depth: int = 1,

@@ -62,7 +62,7 @@ def run(seed: int = 0, smoke: bool = False) -> dict:
     params = emb.init(jax.random.key(0))
     cap = 2048 if smoke else 16_384
 
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
 
     mesh = make_mesh((1, 1), ("data", "model"))
     b = syn.recsys_batch(rng, specs, B, alpha=1.35)

@@ -21,6 +21,8 @@ import json
 import sys
 import traceback
 
+from repro.utils import enable_compile_cache
+
 
 def main(argv=None) -> None:
     ap = argparse.ArgumentParser(description=__doc__)
@@ -31,6 +33,7 @@ def main(argv=None) -> None:
                     help="also write per-bench scalar metrics as JSON "
                     "(input for tools/bench_history.py)")
     opts = ap.parse_args(argv)
+    enable_compile_cache()
     rows = []
     bench_metrics: dict[str, dict] = {}
 

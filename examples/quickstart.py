@@ -30,7 +30,7 @@ def main():
     n_dev = jax.device_count()
     mesh = None
     if n_dev >= 8:
-        from repro.compat import make_mesh
+        from repro.launch.mesh import make_mesh
 
         mesh = make_mesh((2, n_dev // 2), ("data", "model"))
         print(f"mesh: {dict(mesh.shape)}")

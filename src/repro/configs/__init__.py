@@ -56,7 +56,7 @@ def input_specs(arch_id: str, shape: str, mesh=None, multi_pod: bool = False):
     (weak-type-correct, shardable, no device allocation).  `mesh` defaults to
     an AbstractMesh of the production 16x16 pod."""
     if mesh is None:
-        from repro.compat import abstract_mesh
+        from repro.launch.mesh import abstract_mesh
 
         shape_ax = ((2, 16, 16), ("pod", "data", "model")) if multi_pod else (
             (16, 16), ("data", "model"))

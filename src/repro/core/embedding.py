@@ -43,7 +43,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.hotcache.table import (
     HashCacheState,
     cache_insert as hc_insert,
@@ -361,7 +361,7 @@ class DisaggEmbedding:
                 if cache is None:
                     cache_spec = None
                 elif isinstance(cache, HashCacheState):
-                    cache_spec = cache_partition_spec()
+                    cache_spec = cache_partition_spec(cache)
                 else:
                     cache_spec = HotCacheState(ids=P(None), rows=P(None, None))
                 in_specs = (

@@ -5,8 +5,8 @@ instead of the seed's flat replicated slab:
 
   table      — HashCacheState: open-addressing (linear probe) hash table in
                HBM; jit-functional insert with LFU admission/eviction.
-  kernels    — Pallas TPU kernels: fused hash-probe + masked gather +
-               per-bag pooling + miss mask in one pass; scatter swap-in.
+  kernels    — hash probe + miss mask, then the masked gather and per-bag
+               pooling in one Pallas kernel; Pallas scatter swap-in.
   ref        — pure-jnp oracles the kernels are validated against.
   policy     — frequency-aware admission (FreqCacheEmbedding-style).
   miss_path  — HostHashCache mirror + TieredLookupService: only cache

@@ -1,22 +1,20 @@
 """Pallas TPU kernels for the hot-embedding hash cache.
 
-``probe_gather_pool`` is the serving fast path: one kernel fuses the hash
-**probe** (linear window over the open-addressing table), the masked row
-**gather**, the per-bag **pooling** accumulation, and the **miss mask** that
-feeds the tiered miss path — the cached rows never round-trip through HBM
-between those stages.
+``probe_gather_pool`` is the serving fast path: the hash **probe** (linear
+window over the open-addressing table) yields each lookup's slot and the
+**miss mask** that feeds the tiered miss path; the masked row **gather** and
+the per-bag **pooling** then run in one Pallas kernel
+(kernels.embedding_bag), so the cached rows never round-trip through HBM
+between those stages.  The probe is a fixed-width vectorized compare that
+XLA fuses on its own; the kernel needs its result as DMA addresses, which
+is why the slots ride into SMEM instead of being computed there.
 
-TPU-native structure (same scalar-prefetch idiom as kernels.embedding_bag):
-the grid is ``(num_bags, nnz, max_probes)``; the lookup ids ride in SMEM as a
-scalar-prefetch operand so the BlockSpec index_map can compute the probe slot
-``(hash(id) + p) & (C-1)`` and DMA exactly the probed key/row blocks into
-VMEM while the previous step computes.  Consecutive steps of one bag hit the
-same output block, so the accumulator stays VMEM-resident across the whole
-bag (and the miss flag across the whole probe window).
-
-``scatter_update`` is the swap-in kernel: it streams admitted rows into their
-slots in the HBM-resident value table in place (input/output aliasing), one
-row DMA per grid step — the device side of the §3.1.1 cache swap-in.
+``scatter_update`` is the swap-in kernel: it writes admitted rows into
+their slots of the HBM-resident cache in place (input/output aliasing).
+Each write is a read-modify-write of the slot's 128-lane line — a DMA can
+move whole lines only, and neighbouring slots share a line when D < 128 —
+done one row after another, so duplicates resolve last-write-wins: the
+device side of the §3.1.1 cache swap-in.
 """
 from __future__ import annotations
 
@@ -27,122 +25,93 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.hotcache.table import EMPTY_KEY, hash_slots
-
-
-def _probe_slot(ids_ref, flat: int, p, num_slots: int):
-    """Probe slot for prefetched id `ids_ref[flat]` at probe step p."""
-    home = hash_slots(ids_ref[flat], num_slots)
-    return (home + p) & jnp.int32(num_slots - 1)
-
-
-def _probe_kernel(idx_ref, w_ref, key_ref, val_ref, out_ref, miss_ref):
-    b, j, p = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    nnz = pl.num_programs(1)
-
-    @pl.when((j == 0) & (p == 0))
-    def _init_out():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    @pl.when(p == 0)
-    def _init_miss():
-        miss_ref[...] = jnp.ones_like(miss_ref)
-
-    idx = idx_ref[b * nnz + j]
-    hit = (key_ref[0, 0] == idx) & (idx != EMPTY_KEY)
-
-    # Keys are unique, so at most one probe step hits: no double accumulate.
-    @pl.when(hit)
-    def _accumulate():
-        out_ref[...] += val_ref[...].astype(jnp.float32) * w_ref[0, 0]
-        miss_ref[...] = jnp.zeros_like(miss_ref)
+from repro.hotcache.table import HashCacheState, probe
+from repro.kernels.embedding_bag import ID_BLOCK, embedding_bag, rows_per_line
 
 
 @functools.partial(
     jax.jit, static_argnames=("num_bags", "max_probes", "interpret")
 )
 def probe_gather_pool(
-    keys: jax.Array,  # [C] int32 slot keys (EMPTY_KEY = vacant)
-    values: jax.Array,  # [C, D] cached rows; D ideally a multiple of 128
+    cache: HashCacheState,
     ids: jax.Array,  # [N] int32 lookup ids, N = num_bags * nnz
     weights: jax.Array,  # [N] f32 (0.0 masks a slot; 1/count for mean)
     num_bags: int,
     max_probes: int = 8,
     interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
-    """Fused probe+gather+pool: -> (pooled [num_bags, D] f32, miss [N] bool)."""
-    N = ids.shape[0]
-    C, D = values.shape
-    assert N % num_bags == 0, "fixed-nnz layout required"
-    assert C & (C - 1) == 0, "num_slots must be a power of two"
-    nnz = N // num_bags
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(num_bags, nnz, max_probes),
-        in_specs=[
-            pl.BlockSpec((None, 1, 1), lambda b, j, p, idx: (0, b * nnz + j, 0)),
-            pl.BlockSpec(
-                (1, 1),
-                lambda b, j, p, idx: (_probe_slot(idx, b * nnz + j, p, C), 0),
-            ),
-            pl.BlockSpec(
-                (1, D),
-                lambda b, j, p, idx: (_probe_slot(idx, b * nnz + j, p, C), 0),
-            ),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, D), lambda b, j, p, idx: (b, 0)),
-            pl.BlockSpec((1, 1), lambda b, j, p, idx: (b, j)),
-        ],
+    """Probe + gather + pool: -> (pooled [num_bags, D] f32, miss [N] bool)."""
+    C = cache.num_slots
+    if C & (C - 1):
+        raise ValueError("num_slots must be a power of two")
+    slot, hit = probe(cache.keys, ids.astype(jnp.int32), max_probes)
+    pooled = embedding_bag(
+        cache.rows, slot, jnp.where(hit, weights.astype(jnp.float32), 0.0),
+        num_bags, dim=cache.dim, interpret=interpret,
     )
-    pooled, miss = pl.pallas_call(
-        _probe_kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((num_bags, D), jnp.float32),
-            jax.ShapeDtypeStruct((num_bags, nnz), jnp.int32),
-        ],
-        interpret=interpret,
-    )(
-        ids.astype(jnp.int32),
-        weights.astype(jnp.float32).reshape(1, N, 1),
-        keys.reshape(C, 1),
-        values,
-    )
-    return pooled, miss.reshape(N).astype(bool)
+    return pooled, ~hit
 
 
-def _scatter_kernel(slot_ref, row_ref, val_in_ref, out_ref):
-    del slot_ref, val_in_ref  # routing happens in the index_maps
-    out_ref[...] = row_ref[...]
+def _scatter_kernel(slot_ref, row_ref, val_in_ref, val_ref, line, sem, *, dim):
+    del val_in_ref  # aliased with val_ref
+    width = line.shape[1]
+    pack = width // dim
+    group = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1) // dim
+
+    def body(i, carry):
+        s = slot_ref[i]
+
+        @pl.when(s >= 0)
+        def _write():
+            dst = val_ref.at[pl.ds(s // pack, 1)]
+            read = pltpu.make_async_copy(dst, line, sem)
+            read.start()
+            read.wait()
+            line[...] = jnp.where(
+                group == s % pack, row_ref[pl.ds(i, 1), :], line[...]
+            )
+            write = pltpu.make_async_copy(line, dst, sem)
+            write.start()
+            write.wait()
+
+        return carry
+
+    jax.lax.fori_loop(0, slot_ref.shape[0], body, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",), donate_argnums=(0,))
 def scatter_update(
-    values: jax.Array,  # [C, D] cache rows (donated, updated in place)
+    values: jax.Array,  # [C/p, p*D] line-packed cache rows (donated, in place)
     slots: jax.Array,  # [K] int32 target slots (duplicates: last write wins)
     rows: jax.Array,  # [K, D] admitted rows
     interpret: bool = False,
 ) -> jax.Array:
-    """Swap-in: write rows[i] into values[slots[i]] with I/O aliasing."""
+    """Swap-in: write rows[i] into slot slots[i] of values, in place."""
     K, D = rows.shape
-    C = values.shape[0]
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(K,),
-        in_specs=[
-            pl.BlockSpec((1, D), lambda i, slot: (i, 0)),
-            pl.BlockSpec((1, D), lambda i, slot: (slot[i], 0)),
-        ],
-        out_specs=pl.BlockSpec((1, D), lambda i, slot: (slot[i], 0)),
-    )
+    width = values.shape[1]
+    if width != D * rows_per_line(D):
+        raise ValueError(f"values width {width} is not a line of {D}-wide rows")
+    k_pad = -(-K // ID_BLOCK) * ID_BLOCK
+    slots = jnp.pad(slots.astype(jnp.int32), (0, k_pad - K), constant_values=-1)
+    # Each row repeated across its line's lane groups; the kernel keeps the
+    # group of its slot.
+    lines = jnp.pad(jnp.tile(rows.astype(values.dtype), (1, width // D)),
+                    ((0, k_pad - K), (0, 0)))
     return pl.pallas_call(
-        _scatter_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((C, D), values.dtype),
-        # operand order: (slots, rows, values); values aliases the output.
+        functools.partial(_scatter_kernel, dim=D),
+        grid=(k_pad // ID_BLOCK,),
+        in_specs=[
+            pl.BlockSpec((ID_BLOCK,), lambda t: (t,), memory_space=pltpu.SMEM),
+            pl.BlockSpec((ID_BLOCK, width), lambda t: (t, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        out_shape=jax.ShapeDtypeStruct(values.shape, values.dtype),
+        scratch_shapes=[
+            pltpu.VMEM((1, width), values.dtype),
+            pltpu.SemaphoreType.DMA(()),
+        ],
+        # operand order: (slots, lines, values); values aliases the output.
         input_output_aliases={2: 0},
         interpret=interpret,
-    )(slots.astype(jnp.int32), rows.astype(values.dtype), values)
+    )(slots, lines, values)

@@ -5,7 +5,12 @@ seed's flat replicated ``(sorted ids, rows)`` slab.  Layout (all HBM, all
 jit-compatible pytree leaves):
 
   keys  [C]    int32   fused row id per slot; EMPTY_KEY marks a vacant slot.
-  rows  [C, D] float   the cached embedding rows.
+  rows  [C/p, p*D]     the cached embedding rows, packed ``p`` to a 128-lane
+                       line (kernels.embedding_bag.pack_rows): slot s lives
+                       in line s // p, lanes (s % p) * D onward.  Rows of
+                       width D < 128 stored as a plain [C, D] array would be
+                       laid out with C on the lanes on a TPU, and every row
+                       DMA would relayout the whole cache first.
   freq  [C]    int32   decayed LFU counters (admission/eviction evidence).
 
 ``C`` (``num_slots``) is a power of two so the multiplicative hash reduces
@@ -31,6 +36,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.embedding_bag import pack_rows, rows_per_line, take_rows
+
 # Vacant-slot marker. Equals core.embedding.ROW_ID_PAD (int32 max) so padded
 # lookup ids can never alias a live key; kept literal here to avoid an import
 # cycle (core.embedding imports this module for its cache fast path).
@@ -53,16 +60,13 @@ class HashCacheState:
     """Open-addressing hot-row cache (device resident, replicated)."""
 
     keys: jax.Array  # [C] int32, EMPTY_KEY where vacant
-    rows: jax.Array  # [C, D]
+    rows: jax.Array  # [C/p, p*D] line-packed rows (module docstring)
     freq: jax.Array  # [C] int32 LFU counters
+    dim: int = dataclasses.field(metadata=dict(static=True))  # row width D
 
     @property
     def num_slots(self) -> int:
         return int(self.keys.shape[0])
-
-    @property
-    def dim(self) -> int:
-        return int(self.rows.shape[1])
 
     def occupancy(self) -> jax.Array:
         """Number of live entries (traced scalar)."""
@@ -76,8 +80,9 @@ def empty_hash_cache(
         raise ValueError(f"num_slots must be a power of two, got {num_slots}")
     return HashCacheState(
         keys=jnp.full((num_slots,), EMPTY_KEY, jnp.int32),
-        rows=jnp.zeros((num_slots, dim), dtype),
+        rows=pack_rows(jnp.zeros((num_slots, dim), dtype)),
         freq=jnp.zeros((num_slots,), jnp.int32),
+        dim=dim,
     )
 
 
@@ -109,6 +114,26 @@ def probe_slots(
     return (home[..., None] + offs) & jnp.int32(num_slots - 1)
 
 
+def probe(
+    keys: jax.Array, ids: jax.Array, max_probes: int
+) -> tuple[jax.Array, jax.Array]:
+    """Vectorized probe: ids [...] -> (slot [...], hit [...]).
+
+    slot is the id's slot where hit, else its home slot.  One 1-D gather
+    per probe step: XLA's TPU compiler takes tens of seconds over a single
+    [..., P] gather at serving batch sizes."""
+    C = keys.shape[0]
+    home = hash_slots(ids, C)
+    valid = ids != EMPTY_KEY
+    slot, hit = home, jnp.zeros(ids.shape, bool)
+    for p in reversed(range(max_probes)):  # the first matching step wins
+        s = (home + p) & jnp.int32(C - 1)
+        match = (jnp.take(keys, s) == ids) & valid
+        slot = jnp.where(match, s, slot)
+        hit = hit | match
+    return slot, hit
+
+
 def cache_lookup(
     state: HashCacheState,
     ids: jax.Array,
@@ -117,17 +142,12 @@ def cache_lookup(
     """Vectorized probe: ids [...] -> (rows [..., D], hit [...]).
 
     Pure read (freq untouched) so it is safe inside jit/shard_map serving
-    steps.  Misses return zero rows.  This is the portable path; the fused
-    Pallas kernel (kernels.probe_gather_pool) implements the same semantics
-    with pooling folded in for the TPU hot loop.
+    steps.  Misses return zero rows.  This is the portable path; the Pallas
+    kernel (kernels.probe_gather_pool) implements the same semantics with
+    pooling folded in for the TPU hot loop.
     """
-    slots = probe_slots(ids, state.num_slots, max_probes)  # [..., P]
-    kw = jnp.take(state.keys, slots)  # [..., P]
-    match = (kw == ids[..., None]) & (ids != EMPTY_KEY)[..., None]
-    hit = match.any(axis=-1)
-    sel = jnp.argmax(match, axis=-1)
-    slot = jnp.take_along_axis(slots, sel[..., None], axis=-1)[..., 0]
-    rows = jnp.take(state.rows, slot, axis=0)
+    slot, hit = probe(state.keys, ids, max_probes)
+    rows = take_rows(state.rows, slot, state.dim)
     rows = jnp.where(hit[..., None], rows, jnp.zeros((), rows.dtype))
     return rows, hit
 
@@ -160,6 +180,8 @@ def cache_insert(
     """
     thr = jnp.asarray(admission_threshold, jnp.int32)
     K = ids.shape[0]
+    D = state.dim
+    pack = rows_per_line(D)
     ids = ids.astype(jnp.int32)
     freqs = freqs.astype(jnp.int32)
 
@@ -186,9 +208,10 @@ def cache_insert(
         write = (id_i != EMPTY_KEY) & (has_match | fresh_ok)
 
         keys = keys.at[target].set(jnp.where(write, id_i, keys[target]))
-        vals = vals.at[target].set(
-            jnp.where(write, rows[i].astype(vals.dtype), vals[target])
-        )
+        at = (target // pack, (target % pack) * D)
+        old = jax.lax.dynamic_slice(vals, at, (1, D))
+        new = jnp.where(write, rows[i].astype(vals.dtype)[None], old)
+        vals = jax.lax.dynamic_update_slice(vals, new, at)
         new_f = jnp.where(has_match, freq[target] + f_i, f_i)
         freq = freq.at[target].set(jnp.where(write, new_f, freq[target]))
         admitted = admitted.at[i].set(write)
@@ -200,7 +223,7 @@ def cache_insert(
         body,
         (state.keys, state.rows, state.freq, jnp.zeros((K,), bool)),
     )
-    return HashCacheState(keys=keys, rows=vals, freq=freq), admitted
+    return HashCacheState(keys=keys, rows=vals, freq=freq, dim=D), admitted
 
 
 def decay_freq(state: HashCacheState, factor: float) -> HashCacheState:
@@ -209,8 +232,10 @@ def decay_freq(state: HashCacheState, factor: float) -> HashCacheState:
     return dataclasses.replace(state, freq=freq)
 
 
-def cache_partition_spec():
+def cache_partition_spec(state: HashCacheState):
     """Replicated-on-every-chip PartitionSpec pytree for shard_map in_specs."""
     from jax.sharding import PartitionSpec as P
 
-    return HashCacheState(keys=P(None), rows=P(None, None), freq=P(None))
+    return HashCacheState(
+        keys=P(None), rows=P(None, None), freq=P(None), dim=state.dim
+    )

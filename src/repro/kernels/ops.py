@@ -1,9 +1,10 @@
-"""Public jit'd wrappers around the Pallas kernels.
+"""Wrappers that pick between the Pallas kernels and their jnp references.
 
-`use_pallas=False` (default on this CPU container) routes to the pure-jnp
-reference implementations so the same call sites run everywhere; on real TPU
-hardware the kernels lower natively.  `interpret=True` executes the kernel
-body in Python on CPU — the validation mode the tests sweep.
+By default (`use_pallas=False`) every call runs the pure-jnp reference from
+ref.py, on any backend — nothing here checks for a TPU.  `use_pallas=True`
+runs the compiled kernel (TPU only); `interpret=True` runs the kernel body
+through the Pallas interpreter, on any backend (what the CPU tests sweep).
+The served path calls no kernel through this module.
 """
 from __future__ import annotations
 
@@ -16,6 +17,7 @@ import numpy as np
 from repro.kernels import ref
 from repro.kernels.dot_interaction import dot_interaction as _dot_pallas
 from repro.kernels.embedding_bag import embedding_bag as _bag_pallas
+from repro.kernels.embedding_bag import pack_rows
 from repro.kernels.flash_attention import flash_attention as _flash_pallas
 
 
@@ -23,7 +25,13 @@ def embedding_bag(
     table, indices, weights, num_bags, *, use_pallas=False, interpret=False
 ):
     if use_pallas or interpret:
-        return _bag_pallas(table, indices, weights, num_bags, interpret=interpret)
+        # The kernel reads line-packed rows; packing here relayouts the
+        # table on every call, which a caller on a hot path avoids by
+        # storing it packed and calling the kernel directly.
+        return _bag_pallas(
+            pack_rows(table), indices, weights, num_bags,
+            dim=table.shape[1], interpret=interpret,
+        )
     return ref.embedding_bag_ref(table, indices, weights, num_bags)
 
 

@@ -1,4 +1,8 @@
-"""Production mesh builders.
+"""Mesh builders.
+
+Every mesh here has Auto axes: the sharding rules (PartitionSpecs plus
+sharding constraints) assume Auto, and ``jax.make_mesh`` defaults to
+Explicit axes.
 
 `make_production_mesh` is a FUNCTION (not a module constant) so importing this
 module never touches jax device state; the dry-run sets
@@ -6,8 +10,25 @@ XLA_FLAGS=--xla_force_host_platform_device_count=512 before any jax import.
 """
 from __future__ import annotations
 
-from repro.compat import make_mesh
+import jax
+from jax.sharding import AbstractMesh, AxisType
+
 from repro.core.sharding import AXIS_DATA, AXIS_MODEL, AXIS_POD
+
+
+def make_mesh(axis_shapes, axis_names, *, devices=None):
+    """``jax.make_mesh`` with Auto axes."""
+    return jax.make_mesh(
+        axis_shapes, axis_names, devices=devices,
+        axis_types=(AxisType.Auto,) * len(axis_names),
+    )
+
+
+def abstract_mesh(axis_shapes, axis_names):
+    """Device-free AbstractMesh with Auto axes (shape-only builds, dry runs)."""
+    return AbstractMesh(
+        axis_shapes, axis_names, axis_types=(AxisType.Auto,) * len(axis_names)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -1,6 +1,13 @@
 """Disaggregated serving driver: replay a diurnal trace through FlexEMRServer.
 
   PYTHONPATH=src python -m repro.launch.serve --requests 2000
+  PYTHONPATH=src python -m repro.launch.serve --config dlrm-flexemr --row-cut 8
+
+``--config`` picks the model: the small ``dlrm-serve`` (default) or the
+paper's ``dlrm-flexemr`` at its full widths; ``--row-cut N`` divides every
+table's rows by N, for a host or device that cannot hold the declared rows.
+The dense stage is compiled for every batcher bucket before the first
+request (``warmup_s`` in the summary: set-up, not serving time).
 
 Exercises the full §3 pipeline: bucketed batching, the §3.2 rdma engine pool
 (``--engine legacy`` for the pre-pool per-connection threads) with pooling
@@ -35,6 +42,7 @@ exit under the ``slo.`` registry namespace.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import time
 
@@ -58,7 +66,9 @@ from repro.models import recsys as R
 from repro.obs import SloMonitor, SloObjective, Tracer, get_registry
 from repro.runtime.admission import AdmissionController
 from repro.runtime.serving import FlexEMRServer
-from repro.utils import logger
+from repro.utils import enable_compile_cache, logger
+
+SERVED_CONFIGS = ("dlrm-serve", "dlrm-flexemr")
 
 
 def make_serving_dlrm(scale: float = 1.0) -> R.RecsysConfig:
@@ -76,6 +86,28 @@ def make_serving_dlrm(scale: float = 1.0) -> R.RecsysConfig:
         bottom_mlp=(256, 64),
         mlp=(256, 128),
     )
+
+
+def make_config(name: str, scale: float = 1.0, row_cut: int = 1
+                ) -> R.RecsysConfig:
+    """The model to serve, with every table's rows divided by `row_cut`
+    (widths untouched); `scale` sizes the ad-hoc dlrm-serve only."""
+    if name == "dlrm-serve":
+        cfg = make_serving_dlrm(scale)
+    elif name == "dlrm-flexemr":
+        from repro.configs import dlrm_flexemr
+
+        cfg = dlrm_flexemr.make_config()
+    else:
+        raise ValueError(f"unknown config {name!r}; served: {SERVED_CONFIGS}")
+    if row_cut < 1:
+        raise ValueError("row_cut must be >= 1")
+    if row_cut > 1:
+        cfg = dataclasses.replace(cfg, tables=tuple(
+            dataclasses.replace(t, vocab=max(1, t.vocab // row_cut))
+            for t in cfg.tables
+        ))
+    return cfg
 
 
 def _build_chaos(args, tables, tracer):
@@ -121,9 +153,10 @@ def _build_chaos(args, tables, tracer):
     return ChaosInjector(schedule, tracer=tracer)
 
 
-def run(args) -> dict:
-    cfg = make_serving_dlrm(args.scale)
-    rng = np.random.default_rng(args.seed)
+def build(args) -> tuple[R.RecsysConfig, dict, FlexEMRServer]:
+    """Seeded params and the FlexEMRServer that ``run`` serves, as the
+    command-line options describe them."""
+    cfg = make_config(args.config, args.scale, args.row_cut)
     params = R.init_params(cfg, jax.random.key(args.seed))
     tables = make_fused_tables(cfg.tables, cfg.embed_dim, args.num_servers)
     controller = AdaptiveCacheController(
@@ -136,7 +169,6 @@ def run(args) -> dict:
         field_replication=False,
     )
     tracer = Tracer() if getattr(args, "trace", None) else None
-    registry = get_registry()
     slo = SloMonitor(SloObjective(
         latency_target_s=1e-3 * args.slo_target_ms,
     ))
@@ -157,16 +189,27 @@ def run(args) -> dict:
         num_engines=args.num_engines, pushdown=not args.no_pushdown,
         engine=args.engine, pipeline_depth=args.pipeline_depth,
         dedup=not args.no_dedup,
-        tracer=tracer, registry=registry, slo=slo, chaos=chaos,
+        tracer=tracer, registry=get_registry(), slo=slo, chaos=chaos,
         admission=admission, retry_policy=retry_policy,
         degrade_policy=getattr(args, "degrade_policy", "strict"),
     )
+    return cfg, params, server
+
+
+def run(args) -> dict:
+    cfg, _, server = build(args)
+    rng = np.random.default_rng(args.seed)
+    tracer, registry, slo = server.tracer, server.registry, server.slo
+    chaos, admission = server.chaos, server.admission
+    retry_policy = server.retry_policy
     deadline_s = (
         1e-3 * args.deadline_ms if args.deadline_ms is not None else None
     )
     try:
         from repro.runtime.admission import ShedError
 
+        warmup_s = server.warmup()
+        logger.info("dense stage compiled for buckets: %s", warmup_s)
         t0 = time.time()
         if args.arrival == "closed":
             sizes = syn.diurnal_batches(
@@ -227,6 +270,7 @@ def run(args) -> dict:
         wall = time.time() - t0
         out = server.metrics.summary()
         out["throughput_rps"] = submitted / wall
+        out["warmup_s"] = warmup_s
         if driver_stats is not None:
             out["loadgen"] = driver_stats
         out["slo"] = slo.summary()
@@ -258,7 +302,7 @@ def run(args) -> dict:
                 ),
             }
         logger.info("serve summary: %s", json.dumps(out, indent=1))
-        if tracer is not None:
+        if tracer.enabled:
             tracer.save(args.trace)
             logger.info(
                 "trace: %d events -> %s (open in https://ui.perfetto.dev)",
@@ -272,8 +316,13 @@ def run(args) -> dict:
         server.close()
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
+    ap.add_argument("--config", choices=SERVED_CONFIGS, default="dlrm-serve",
+                    help="model to serve: the small dlrm-serve or the "
+                    "paper's dlrm-flexemr at its full widths")
+    ap.add_argument("--row-cut", type=int, default=1, metavar="N",
+                    help="divide every table's rows by N (widths kept)")
     ap.add_argument("--requests", type=int, default=1000)
     ap.add_argument("--num-servers", type=int, default=8)
     ap.add_argument("--num-engines", type=int, default=4,
@@ -285,7 +334,8 @@ def main():
                     help="batches in flight: N+1's lookup posts before N's "
                     "dense stage runs (1 = closed loop, no overlap)")
     ap.add_argument("--cache-rows", type=int, default=65536)
-    ap.add_argument("--scale", type=float, default=1.0)
+    ap.add_argument("--scale", type=float, default=1.0,
+                    help="table-size multiplier of dlrm-serve")
     ap.add_argument("--no-pushdown", action="store_true",
                     help="disable pooling pushdown (near-memory segment "
                     "reduction on the miss path); lookups ship raw rows "
@@ -355,7 +405,12 @@ def main():
                     "until restore (default), degrade answers the cache "
                     "tier's best partial and flags the request, block "
                     "fails fast.  Pooled engine only")
-    args = ap.parse_args()
+    return ap
+
+
+def main():
+    args = build_parser().parse_args()
+    enable_compile_cache()
     run(args)
 
 

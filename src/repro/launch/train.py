@@ -27,7 +27,7 @@ from repro.models import recsys as R
 from repro.models import transformer as T
 from repro.optim import optimizers as opt_lib
 from repro.runtime.elastic import reshard_params
-from repro.utils import logger, tree_num_params
+from repro.utils import enable_compile_cache, logger, tree_num_params
 
 
 def make_dlrm_100m() -> R.RecsysConfig:
@@ -150,6 +150,7 @@ def main():
     ap.add_argument("--reshard-at", type=int, default=0)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
+    enable_compile_cache()
     out = train_recsys(args) if args.model == "dlrm" else train_lm(args)
     logger.info("done: %s", out)
     assert out["final_loss"] < out["first_loss"], "loss must improve"

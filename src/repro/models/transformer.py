@@ -29,7 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core.sharding import AXIS_DATA, AXIS_MODEL, AXIS_POD
 from repro.models import layers as L
 from repro.models.moe import MoEConfig, moe_apply_local, moe_init

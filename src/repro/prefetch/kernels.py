@@ -9,11 +9,12 @@ the oracle.  On the TPU serving path this runs on the swap-in stream right
 next to hotcache.kernels.scatter_update, so neighbor selection never
 round-trips candidate tiles through HBM.
 
-Structure: grid = (M,); each step owns one [1, L] score row.  Selection is
-an unrolled-by-fori_loop iterative argmax with a `taken` mask — ties break
-to the lowest column index, matching the oracle's stable descending sort.
-The per-step outputs land in [1, K] blocks, accumulated as values and
-written once (no dynamic stores).
+Structure: grid = (M / 8,); each step owns an [8, L] tile of score rows
+(one f32 sublane tile; M is padded with -inf rows).  Selection is an
+unrolled-by-fori_loop iterative argmax per row with a `taken` mask — ties
+break to the lowest column index, matching the oracle's stable descending
+sort.  The per-step outputs land in [8, K] blocks, accumulated as values
+and written once (no dynamic stores).
 """
 from __future__ import annotations
 
@@ -28,22 +29,27 @@ def _round_up(n: int, m: int) -> int:
     return ((n + m - 1) // m) * m
 
 
+_ROWS = 8  # score rows per grid step: one f32 sublane tile
+
+
 def _topk_kernel(s_ref, vals_ref, idx_ref, *, k: int):
-    scores = s_ref[...]  # [1, L]
-    L = scores.shape[1]
-    col = jax.lax.broadcasted_iota(jnp.int32, (1, L), 1)
-    kcol = jax.lax.broadcasted_iota(jnp.int32, (1, k), 1)
+    scores = s_ref[...]  # [R, L]
+    R, L = scores.shape
+    col = jax.lax.broadcasted_iota(jnp.int32, (R, L), 1)
+    kcol = jax.lax.broadcasted_iota(jnp.int32, (R, k), 1)
     neg_inf = jnp.float32(-jnp.inf)
 
+    # `taken` is carried as int32: Mosaic cannot carry a bool vector
+    # through a loop.
     def body(j, carry):
         taken, vals, idxs = carry
-        avail = jnp.where(taken, neg_inf, scores)
-        best = jnp.max(avail)
+        avail = jnp.where(taken != 0, neg_inf, scores)
+        best = jnp.max(avail, axis=1, keepdims=True)
         # Lowest untaken column attaining the max — on an all--inf remainder
         # this still walks the columns in index order, like the stable sort.
-        cand = (~taken) & (avail == best)
-        pick = jnp.min(jnp.where(cand, col, jnp.int32(L)))
-        taken = taken | (col == pick)
+        cand = (taken == 0) & (avail == best)
+        pick = jnp.min(jnp.where(cand, col, jnp.int32(L)), axis=1, keepdims=True)
+        taken = jnp.where(col == pick, 1, taken)
         vals = jnp.where(kcol == j, best, vals)
         idxs = jnp.where(kcol == j, pick, idxs)
         return taken, vals, idxs
@@ -53,9 +59,9 @@ def _topk_kernel(s_ref, vals_ref, idx_ref, *, k: int):
         k,
         body,
         (
-            jnp.zeros((1, L), bool),
-            jnp.zeros((1, k), jnp.float32),
-            jnp.zeros((1, k), jnp.int32),
+            jnp.zeros((R, L), jnp.int32),
+            jnp.zeros((R, k), jnp.float32),
+            jnp.zeros((R, k), jnp.int32),
         ),
     )
     vals_ref[...] = vals
@@ -78,21 +84,22 @@ def topk_neighbor_select(
     if k > L:
         raise ValueError(f"k={k} exceeds candidate width {L}")
     Lp = _round_up(max(L, 128), 128)
-    s = jnp.full((M, Lp), -jnp.inf, jnp.float32).at[:, :L].set(
+    Mp = _round_up(max(M, 1), _ROWS)
+    s = jnp.full((Mp, Lp), -jnp.inf, jnp.float32).at[:M, :L].set(
         scores.astype(jnp.float32)
     )
     vals, idx = pl.pallas_call(
         functools.partial(_topk_kernel, k=k),
-        grid=(M,),
-        in_specs=[pl.BlockSpec((1, Lp), lambda m: (m, 0))],
+        grid=(Mp // _ROWS,),
+        in_specs=[pl.BlockSpec((_ROWS, Lp), lambda m: (m, 0))],
         out_specs=[
-            pl.BlockSpec((1, k), lambda m: (m, 0)),
-            pl.BlockSpec((1, k), lambda m: (m, 0)),
+            pl.BlockSpec((_ROWS, k), lambda m: (m, 0)),
+            pl.BlockSpec((_ROWS, k), lambda m: (m, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((M, k), jnp.float32),
-            jax.ShapeDtypeStruct((M, k), jnp.int32),
+            jax.ShapeDtypeStruct((Mp, k), jnp.float32),
+            jax.ShapeDtypeStruct((Mp, k), jnp.int32),
         ],
         interpret=interpret,
     )(s)
-    return vals, idx
+    return vals[:M], idx[:M]

@@ -426,6 +426,21 @@ class FlexEMRServer:
             )[:, 0]
         raise NotImplementedError(cfg.arch)
 
+    def warmup(self) -> dict[int, float]:
+        """Compile the dense stage for every batcher bucket before traffic
+        arrives; returns the seconds each bucket took (compilation is
+        set-up, not serving time)."""
+        F, D = self.cfg.num_fields, self.cfg.embed_dim
+        seconds = {}
+        for b in self.batcher.buckets:
+            t0 = time.perf_counter()
+            self._dense(
+                jnp.zeros((b, F, D), jnp.float32),
+                jnp.zeros((b, self.cfg.n_dense), jnp.float32),
+            ).block_until_ready()
+            seconds[b] = time.perf_counter() - t0
+        return seconds
+
     # ---------------------------------------------------------------- lookup
 
     def _pool_remote_async(self, indices: np.ndarray, cold_mask: np.ndarray):
@@ -873,8 +888,10 @@ class FlexEMRServer:
     def close(self):
         """Drain the pipeline (in-flight lookups complete and merge — never
         dropped mid-wire), then shut the engine down.  A batch that FAILED
-        in flight is logged, not raised: close must always reach
-        service.close() or the engine-pool threads leak."""
+        in flight does not stop the drain — close must always reach
+        service.close() or the engine-pool threads leak — and the first
+        such failure is re-raised once the engine is down."""
+        failure = None
         try:
             if self.chaos is not None:
                 # Recover every live fault first so the drain below runs
@@ -884,9 +901,13 @@ class FlexEMRServer:
                 entry = self._pipeline.popleft()
                 try:
                     entry.pending.wait()
-                except Exception:  # noqa: BLE001
+                except Exception as exc:  # noqa: BLE001
                     logger.exception(
                         "pipeline drain: in-flight batch failed"
                     )
+                    if failure is None:
+                        failure = exc
         finally:
             self.service.close()
+        if failure is not None:
+            raise failure

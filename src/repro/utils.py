@@ -1,9 +1,12 @@
-"""Small shared utilities: pytree helpers, timing, deterministic rng streams."""
+"""Small shared utilities: pytree helpers, timing, deterministic rng streams,
+and the persistent compile-cache switch the entry points call."""
 from __future__ import annotations
 
 import contextlib
 import dataclasses
 import logging
+import os
+import pathlib
 import time
 from typing import Any, Callable, Iterator
 
@@ -17,6 +20,21 @@ if not logger.handlers:
     _h.setFormatter(logging.Formatter("[%(levelname)s %(name)s] %(message)s"))
     logger.addHandler(_h)
     logger.setLevel(logging.INFO)
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Entry points call this, never an import.  A ``JAX_COMPILATION_CACHE_DIR``
+    set by whoever runs the program is read by JAX itself and wins; otherwise
+    the cache goes to a fixed ``<checkout>/.jax_cache``, since the directory
+    must not move between runs for entries to be found again."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(pathlib.Path(__file__).resolve().parents[2] / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def tree_size_bytes(tree: Any) -> int:

@@ -8,17 +8,6 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-# Property tests use hypothesis when available; this container cannot install
-# it, so fall back to the seeded API-compatible stub (tests/_hypothesis_fallback).
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    sys.path.insert(0, os.path.dirname(__file__))
-    import _hypothesis_fallback
-
-    sys.modules["hypothesis"] = _hypothesis_fallback
-    sys.modules["hypothesis.strategies"] = _hypothesis_fallback.strategies
-
 import numpy as np
 import pytest
 
@@ -32,6 +21,6 @@ def rng():
 def trivial_mesh():
     """1x1 mesh on the single CPU device: exercises every shard_map code path
     (psum over singleton axes) without forcing a device count."""
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
 
     return make_mesh((1, 1), ("data", "model"))

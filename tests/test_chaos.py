@@ -303,7 +303,9 @@ def _serve_chaos(cfg, params, tables, reqs, depth, dedup, chaos=None,
     server = FlexEMRServer(
         cfg, params, tables, controller=_controller(cfg),
         cache_refresh_every=3, pipeline_depth=depth, hedge_timeout=0.05,
-        dedup=dedup, batcher=BucketBatcher(buckets=(8,), max_wait=0.001),
+        # A poll window no busy machine outlasts: the queued requests always
+        # cut full batches of 8, so the batch count replays exactly.
+        dedup=dedup, batcher=BucketBatcher(buckets=(8,), max_wait=0.05),
         chaos=chaos, registry=registry or MetricsRegistry(), slo=slo,
     )
     try:
@@ -616,7 +618,7 @@ def test_live_reshard_grow_shrink_under_traffic(chaos_fixture):
     server = FlexEMRServer(
         cfg, params, tables, controller=_controller(cfg),
         cache_refresh_every=3, pipeline_depth=2, hedge_timeout=0.05,
-        batcher=BucketBatcher(buckets=(8,), max_wait=0.001),
+        batcher=BucketBatcher(buckets=(8,), max_wait=0.05),  # full batches
         registry=MetricsRegistry(),
     )
     try:

@@ -122,7 +122,7 @@ def test_chunked_lookup_matches(trivial_mesh, rng):
 def test_hot_cache_is_transparent(cache_size, seed):
     """Property: any hot set leaves lookup results unchanged."""
     import jax as _jax
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
 
     mesh = make_mesh((1, 1), ("data", "model"))
     specs = _specs()

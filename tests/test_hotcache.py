@@ -14,6 +14,7 @@ from repro.hotcache import ref as HREF
 from repro.hotcache.kernels import probe_gather_pool, scatter_update
 from repro.hotcache.miss_path import HostHashCache, TieredLookupService
 from repro.hotcache.policy import AdmissionPolicy
+from repro.kernels.embedding_bag import pack_rows, unpack_rows
 from repro.hotcache.table import (
     EMPTY_KEY,
     cache_insert,
@@ -94,7 +95,7 @@ def test_insert_probe_evict_matches_dict_oracle(seed, thr):
 
     keys = np.asarray(state.keys)
     freq = np.asarray(state.freq)
-    vals = np.asarray(state.rows)
+    vals = np.asarray(unpack_rows(state.rows, D))
     want_keys = np.full((C,), EMPTY_KEY, np.int64)
     for s, (k, r, f) in oracle.items():
         want_keys[s] = k
@@ -122,7 +123,9 @@ def test_insert_probe_evict_matches_dict_oracle(seed, thr):
 
 
 @pytest.mark.parametrize(
-    "C,D,bags,nnz,probes", [(64, 128, 4, 1, 4), (256, 128, 16, 4, 8), (512, 256, 8, 8, 8)]
+    "C,D,bags,nnz,probes",
+    [(64, 128, 4, 1, 4), (256, 128, 16, 4, 8), (512, 256, 8, 8, 8),
+     (256, 64, 16, 4, 8), (128, 32, 8, 3, 4)],
 )
 def test_probe_gather_pool_kernel_vs_ref(C, D, bags, nnz, probes, rng):
     state = empty_hash_cache(C, D)
@@ -143,11 +146,12 @@ def test_probe_gather_pool_kernel_vs_ref(C, D, bags, nnz, probes, rng):
         np.float32
     )
     pooled, miss = probe_gather_pool(
-        state.keys, state.rows, jnp.asarray(q), jnp.asarray(w), bags,
+        state, jnp.asarray(q), jnp.asarray(w), bags,
         max_probes=probes, interpret=True,
     )
     want_pooled, want_miss = HREF.probe_gather_pool_ref(
-        state.keys, state.rows, jnp.asarray(q), jnp.asarray(w), bags, probes
+        state.keys, unpack_rows(state.rows, D), jnp.asarray(q),
+        jnp.asarray(w), bags, probes,
     )
     np.testing.assert_allclose(
         np.asarray(pooled), np.asarray(want_pooled), rtol=1e-5, atol=1e-5
@@ -158,14 +162,28 @@ def test_probe_gather_pool_kernel_vs_ref(C, D, bags, nnz, probes, rng):
     np.testing.assert_array_equal(~np.asarray(hit), np.asarray(miss))
 
 
-def test_scatter_update_kernel_vs_ref(rng):
-    C, D, K = 128, 128, 32
+def _check_scatter_update(D, rng):
+    C, K = 128, 32
     values = jnp.asarray(rng.normal(size=(C, D)).astype(np.float32))
     slots = rng.choice(C, K, replace=False).astype(np.int32)
+    # neighbours sharing a line, and a repeated slot (last write wins)
+    slots[1], slots[2] = slots[0] ^ 1, slots[0]
     rows = rng.normal(size=(K, D)).astype(np.float32)
     want = HREF.scatter_update_ref(values, jnp.asarray(slots), jnp.asarray(rows))
-    got = scatter_update(values, jnp.asarray(slots), jnp.asarray(rows), interpret=True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=0, atol=0)
+    got = scatter_update(
+        pack_rows(values), jnp.asarray(slots), jnp.asarray(rows), interpret=True
+    )
+    np.testing.assert_array_equal(
+        np.asarray(unpack_rows(got, D)), np.asarray(want)
+    )
+
+
+def test_scatter_update_kernel_vs_ref(rng):
+    _check_scatter_update(128, rng)
+
+
+def test_scatter_update_kernel_vs_ref_packed_lines(rng):
+    _check_scatter_update(64, rng)
 
 
 # ----------------------------------------------- DisaggEmbedding integration

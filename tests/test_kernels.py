@@ -6,32 +6,42 @@ import pytest
 
 from repro.kernels import ref as REF
 from repro.kernels.dot_interaction import dot_interaction
-from repro.kernels.embedding_bag import embedding_bag
+from repro.kernels.embedding_bag import embedding_bag, pack_rows
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.ops import bag_lookup, dot_interaction_triu
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize(
-    "V,D,bags,nnz", [(64, 128, 4, 1), (200, 128, 16, 4), (512, 256, 8, 8)]
+    "V,D,bags,nnz",
+    [(64, 128, 4, 1), (200, 128, 16, 4), (512, 256, 8, 8), (101, 64, 13, 4),
+     (50, 32, 7, 3)],
 )
 def test_embedding_bag_sweep(dtype, V, D, bags, nnz, rng):
     table = jnp.asarray(rng.normal(size=(V, D)), dtype)
     idx = jnp.asarray(rng.integers(0, V, bags * nnz).astype(np.int32))
     w = jnp.asarray((rng.random(bags * nnz) > 0.25).astype(np.float32))
-    out = embedding_bag(table, idx, w, bags, interpret=True)
+    out = embedding_bag(pack_rows(table), idx, w, bags, dim=D, interpret=True)
     want = REF.embedding_bag_ref(table, idx, w, bags)
     tol = 1e-5 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), rtol=tol, atol=tol)
 
 
-def test_bag_lookup_wrapper(rng):
-    table = jnp.asarray(rng.normal(size=(100, 128)).astype(np.float32))
+def _check_bag_lookup_wrapper(D, rng):
+    table = jnp.asarray(rng.normal(size=(100, D)).astype(np.float32))
     idx = jnp.asarray(rng.integers(0, 100, (4, 3, 2)).astype(np.int32))
     msk = jnp.asarray(rng.random((4, 3, 2)) > 0.3)
     out = bag_lookup(table, idx, msk, interpret=True)
     rows = np.asarray(table)[np.asarray(idx)] * np.asarray(msk)[..., None]
     np.testing.assert_allclose(np.asarray(out), rows.sum(axis=2), rtol=1e-5, atol=1e-5)
+
+
+def test_bag_lookup_wrapper(rng):
+    _check_bag_lookup_wrapper(128, rng)
+
+
+def test_bag_lookup_wrapper_packed_lines(rng):
+    _check_bag_lookup_wrapper(64, rng)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])

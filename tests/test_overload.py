@@ -409,6 +409,32 @@ def test_block_policy_fails_fast_without_parking(rng):
         svc.close()
 
 
+def test_server_close_reraises_inflight_failure(tiny, rng):
+    """A batch that fails while close() drains it does not stop the drain
+    or leak engine threads, and close() re-raises it afterwards."""
+    cfg, params, tables = tiny
+    server = FlexEMRServer(
+        cfg, params, tables, pipeline_depth=2, hedge_timeout=None,
+        batcher=BucketBatcher(buckets=(16,), max_wait=0.005),
+        degrade_policy="block",
+    )
+    pool = server.service.pool
+    pool.mark_shard_dropped(0, DegradedShard(
+        pool.servers[0], np.zeros(0, np.int64),
+        np.zeros((0, cfg.embed_dim), np.float32),
+    ))
+    for _ in range(32):
+        server.submit(_payload(rng, cfg))
+    while len(server._pipeline) < 2 and server._admit_next():
+        pass
+    assert len(server._pipeline) == 2
+    with pytest.raises(ShardUnavailableError):
+        server.close()
+    assert not server._pipeline
+    assert all(not t.is_alive() for t in pool.threads)
+    server.close()  # idempotent: nothing left to drain or re-raise
+
+
 def test_degrade_policy_validated():
     with pytest.raises(ValueError, match="degrade_policy"):
         _pool_setup(degrade_policy="nope")

@@ -237,3 +237,31 @@ def test_prefetch_iterator_restartable():
     assert first["step"] == 5
     assert it.state()["step"] == 6
     it.close()
+
+
+def test_serve_launcher_cuts_rows_and_keeps_widths():
+    from repro.configs import dlrm_flexemr
+    from repro.launch import serve
+
+    full = dlrm_flexemr.make_config()
+    cut = serve.make_config("dlrm-flexemr", row_cut=8)
+    assert [t.vocab for t in cut.tables] == [t.vocab // 8 for t in full.tables]
+    assert (cut.embed_dim, cut.bottom_mlp, cut.mlp, cut.num_fields) == (
+        full.embed_dim, full.bottom_mlp, full.mlp, full.num_fields)
+    with pytest.raises(ValueError):
+        serve.make_config("dlrm-flexemr", row_cut=0)
+
+
+def test_serve_launcher_runs_dlrm_flexemr_widths():
+    """`launch.serve` end to end at the paper model's widths (rows cut to a
+    few thousand): every bucket's dense stage compiles before traffic and
+    every request retires."""
+    from repro.launch import serve
+
+    args = serve.build_parser().parse_args(
+        ["--config", "dlrm-flexemr", "--row-cut", "100000",
+         "--requests", "96", "--num-engines", "2"]
+    )
+    out = serve.run(args)
+    assert out["requests"] == 96
+    assert sorted(out["warmup_s"]) == [32, 64, 128, 256, 512, 1024]

@@ -28,7 +28,7 @@ def _run(code: str):
 
 PREAMBLE = """
 import numpy as np, jax, jax.numpy as jnp
-from repro.compat import make_mesh
+from repro.launch.mesh import make_mesh
 mesh = make_mesh((2, 4), ("data", "model"))
 rng = np.random.default_rng(0)
 """

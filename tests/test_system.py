@@ -38,7 +38,7 @@ def test_registry_complete():
 def test_cell_builds_are_structured():
     """Every (arch x shape) build produces matching args/shardings trees
     (uses the production 16x16 mesh abstractly — no device allocation)."""
-    from repro.compat import abstract_mesh
+    from repro.launch.mesh import abstract_mesh
 
     mesh = abstract_mesh((16, 16), ("data", "model"))
     for arch_id in configs.ASSIGNED:
