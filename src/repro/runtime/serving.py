@@ -56,6 +56,7 @@ from repro.models import recsys as R
 from repro.obs.metrics import Histogram, get_registry
 from repro.obs.trace import (
     CAT_ADMISSION,
+    CAT_CACHE,
     CAT_DENSE,
     CAT_LOOKUP,
     CAT_SERVE,
@@ -585,7 +586,12 @@ class FlexEMRServer:
 
     def _admit_next(self) -> bool:
         """Poll + pad one batch and post its tiered lookup (probe phase)."""
+        tracer = self.tracer
+        t_poll = time.perf_counter() if tracer.enabled else 0.0
         polled = self.batcher.poll()
+        if tracer.enabled:
+            self._span("poll", CAT_SERVE, t_poll,
+                       requests=0 if polled is None else len(polled[1]))
         if polled is None:
             return False
         bucket, reqs = polled
@@ -597,8 +603,6 @@ class FlexEMRServer:
             # fires here, before batch k's own lookup posts, so its WRs
             # already see the degraded world.
             self.chaos.on_admit()
-        tracer = self.tracer
-        t_adm = tracer.now() if tracer.enabled else 0.0
         t0 = time.perf_counter()
         F, NNZ = self.cfg.num_fields, self.cfg.max_nnz
         batch = self.batcher.pad_batch(
@@ -611,16 +615,16 @@ class FlexEMRServer:
             },
         )
         pending = self._tiered.lookup_begin(batch["indices"], batch["mask"])
+        t_admit_end = time.perf_counter()
         if tracer.enabled:
             tracer.complete(
-                "admit", CAT_SERVE, t_adm, tracer.now() - t_adm,
+                "admit", CAT_SERVE, t0 - tracer.epoch, t_admit_end - t0,
                 tid=TID_RANKER,
                 args={"bucket": bucket, "requests": len(reqs),
                       "inflight": len(self._pipeline) + 1},
             )
         self._pipeline.append(
-            _InflightBatch(bucket, reqs, batch, pending, t0,
-                           time.perf_counter())
+            _InflightBatch(bucket, reqs, batch, pending, t0, t_admit_end)
         )
         self.metrics.pipeline_occupancy = len(self._pipeline)
         return True
@@ -651,7 +655,7 @@ class FlexEMRServer:
                 self.metrics.hedges += 1
         if tracer.enabled:
             tracer.complete(
-                "lookup_stall", CAT_LOOKUP, tracer.now() - stall, stall,
+                "lookup_stall", CAT_LOOKUP, t_wait - tracer.epoch, stall,
                 tid=TID_RANKER,
                 args={"bucket": bucket, "hedged": pending.hedged},
             )
@@ -688,22 +692,24 @@ class FlexEMRServer:
         lats = [t_retire - r.arrival for r in reqs]
         self.metrics.observe_attribution(attr, queue_waits, sum(lats))
         if tracer.enabled:
-            now = tracer.now()
-            # Same deltas the metrics accumulated: dense span ==
-            # serve.dense_seconds contribution, batch span == admit->retire.
+            # Stamped where the work started, with the same deltas the
+            # metrics accumulated: dense span == serve.dense_seconds
+            # contribution, batch span == admit->retire.
             tracer.complete(
-                "dense", CAT_DENSE, now - d_dense, d_dense, tid=TID_RANKER,
+                "dense", CAT_DENSE, t1 - tracer.epoch, d_dense,
+                tid=TID_RANKER,
                 args={"bucket": bucket, "batch_size": len(reqs)},
             )
             tracer.complete(
-                "batch", CAT_SERVE, now - dt, dt, tid=TID_RANKER,
+                "batch", CAT_SERVE, t0 - tracer.epoch, dt, tid=TID_RANKER,
                 args={"bucket": bucket, "requests": len(reqs),
                       "n": self.metrics.batches},
             )
             # One instant per batch carrying the stage breakdown — what
             # tools/trace_export.py --attribution renders into a table.
             tracer.instant(
-                "attribution", CAT_SERVE, now, tid=TID_RANKER,
+                "attribution", CAT_SERVE, t_retire - tracer.epoch,
+                tid=TID_RANKER,
                 args={"bucket": bucket, "requests": len(reqs),
                       "total_s": round(dt, 9),
                       "queue_wait_mean_s": round(
@@ -750,7 +756,12 @@ class FlexEMRServer:
                     args={"depth": self.admission.depth,
                           "max_depth": self.admission.max_depth},
                 )
+        if tracer.enabled:
+            # Retire bookkeeping after the dense stage: attribution, the
+            # latency histogram, the SLO feed, brownout flags, admission.
+            self._span("account", CAT_SERVE, t_retire, requests=len(reqs))
         if self.controller is not None:
+            t_heat = time.perf_counter() if tracer.enabled else 0.0
             if pending.unique_ids is not None:
                 # Heat off the hot path: the admit-phase dedup prepass
                 # already aggregated this batch's (unique id, per-touch
@@ -765,13 +776,31 @@ class FlexEMRServer:
                 fused = batch["indices"].astype(np.int64) \
                     + self._offsets[None, :, None]
                 self.controller.observe(bucket, fused[batch["mask"]])
+            if tracer.enabled:
+                self._span("heat", CAT_CACHE, t_heat)
             if self.metrics.batches % self.cache_refresh_every == 0:
                 self._apply_cache_plan(bucket)
         return {"bucket": bucket, "scores": scores, "latency_s": dt,
                 "degraded": degraded}
 
+    def _span(self, name: str, cat: str, t_start: float, **args) -> None:
+        """One serving-thread span from the perf_counter stamp ``t_start``
+        to now, joined to its batch by ``args.batch``."""
+        tracer = self.tracer
+        tracer.complete(
+            name, cat, t_start - tracer.epoch, time.perf_counter() - t_start,
+            tid=TID_RANKER, args={"batch": self.metrics.batches, **args},
+        )
+
     def _apply_cache_plan(self, current_batch: int) -> None:
+        # Traced as "refresh" with its four phases nested inside; each phase
+        # takes its own stamp, so no child starts at its parent's instant.
+        tracer = self.tracer
+        t_refresh = time.perf_counter() if tracer.enabled else 0.0
+        t = time.perf_counter() if tracer.enabled else 0.0
         plan = self.controller.plan(current_batch)
+        if tracer.enabled:
+            self._span("refresh_plan", CAT_CACHE, t)
         cache = self._tiered.cache
         if cache.num_slots != plan.hash_slots:
             # Resize = rebuild: the probe geometry depends on num_slots.
@@ -790,11 +819,16 @@ class FlexEMRServer:
                 if len(plan.hot_freqs) >= k
                 else np.ones((k,), np.int64)
             )
+            t = time.perf_counter() if tracer.enabled else 0.0
             rows = self.table_np[ids]  # swap-in fetch (RDMA on real hardware)
             # Only rows not already resident cost wire bytes to fetch.
             _, already = cache.probe(ids)
             entry = 4 + rows.shape[1] * rows.dtype.itemsize
-            self._plan_swap_in_bytes += int((~already).sum()) * entry
+            fresh = int((~already).sum())
+            self._plan_swap_in_bytes += fresh * entry
+            if tracer.enabled:
+                self._span("refresh_fetch", CAT_CACHE, t, rows=k, fresh=fresh)
+                t = time.perf_counter()
             # The planned rows ARE the chosen hot set: threshold 1 (always
             # admit); plan.admission_threshold gates runtime misses instead.
             cache.insert(ids, rows, freqs, 1.0)
@@ -804,7 +838,11 @@ class FlexEMRServer:
                 self.prefetcher.set_byte_budget(plan.prefetch_budget_bytes)
                 self.prefetcher.piggyback(ids[~already], cache, self.service)
                 self.prefetcher.decay()
+            if tracer.enabled:
+                self._span("refresh_insert", CAT_CACHE, t, rows=k,
+                           fresh=fresh)
         if hasattr(self.service, "set_shard_affinity"):
+            t = time.perf_counter() if tracer.enabled else 0.0
             # Skew-aware dealing (§3.2 follow-on): feed the controller's
             # per-shard heat into the pool's shard->thread table so hot
             # shards spread across engine threads *before* work stealing
@@ -813,7 +851,11 @@ class FlexEMRServer:
                 self.tables.rows_per_shard, self.tables.num_shards
             )
             self.service.set_shard_affinity(heat if heat.sum() > 0 else None)
+            if tracer.enabled:
+                self._span("refresh_affinity", CAT_CACHE, t)
         logger.info("cache plan applied: %s", plan.reason)
+        if tracer.enabled:
+            self._span("refresh", CAT_CACHE, t_refresh, rows=k)
 
     def reshard(self, new_num_shards: int) -> dict:
         """Quiesce-free live reshard: re-partition the embedding tier to

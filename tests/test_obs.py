@@ -5,8 +5,10 @@ The load-bearing contracts:
     registry fully on vs off, across ``pipeline_depth`` {1, 2, 4} ×
     hedge {off, forced} × wire-dedup on/off;
   * spans are well-formed — no negative durations, every per-WR virtual
-    span nests inside its batch's ``lookup_batch`` span, and the Chrome
-    export round-trips through ``tools/trace_export.py`` validation;
+    span nests inside its batch's ``lookup_batch`` span, the serving
+    thread's wall-clock spans are nested or disjoint (each stamped where
+    its work started), and the Chrome export round-trips through
+    ``tools/trace_export.py`` validation;
   * the trace and the metrics snapshot agree (sum-consistency): spans are
     emitted from the exact deltas the counters accumulate;
   * the registry is thread-safe under concurrent updates + snapshots, and
@@ -38,7 +40,7 @@ from repro.obs import (
     P2Quantile,
     Tracer,
 )
-from repro.obs.trace import PID_VIRTUAL, PID_WALL, TID_VBATCH
+from repro.obs.trace import PID_VIRTUAL, PID_WALL, TID_RANKER, TID_VBATCH
 from repro.rdma import PooledLookupService
 from repro.runtime.serving import FlexEMRServer, ServeMetrics
 
@@ -268,11 +270,17 @@ def obs_fixture():
     return cfg, params, tables, reqs
 
 
+REFRESH_EVERY = 3
+REFRESH_PHASES = ("refresh_plan", "refresh_fetch", "refresh_insert",
+                  "refresh_affinity")
+
+
 def _serve(cfg, params, tables, reqs, depth=2, hedge=None, dedup=True,
            tracer=None, registry=None):
     server = FlexEMRServer(
         cfg, params, tables, controller=_controller(cfg),
-        cache_refresh_every=3, pipeline_depth=depth, hedge_timeout=hedge,
+        cache_refresh_every=REFRESH_EVERY, pipeline_depth=depth,
+        hedge_timeout=hedge,
         dedup=dedup, batcher=BucketBatcher(buckets=(8,), max_wait=0.001),
         tracer=tracer, registry=registry,
     )
@@ -291,6 +299,30 @@ def _serve(cfg, params, tables, reqs, depth=2, hedge=None, dedup=True,
     finally:
         server.close()
     return outs, metrics, engine
+
+
+def _ranker_spans(tracer, name=None):
+    """The serving thread's wall-clock spans as (start, end, name), without
+    the whole-batch span (it overlaps the pipelined batches)."""
+    return [(e["ts"], e["ts"] + e["dur"], e["name"])
+            for e in tracer.events(name=name)
+            if e["ph"] == "X" and e["pid"] == PID_WALL
+            and e["tid"] == TID_RANKER and e["name"] != "batch"]
+
+
+def _assert_nested_or_disjoint(tracer, tol=1e-9):
+    """Every pair of ranker-row spans (``batch`` aside) is nested or
+    disjoint: what the benchmark's idle-gap labelling assumes."""
+    stack = []
+    for s, e, name in sorted(_ranker_spans(tracer),
+                             key=lambda x: (x[0], -x[1])):
+        while stack and stack[-1][1] <= s + tol:
+            stack.pop()
+        if stack:
+            ps, pe, pname = stack[-1]
+            assert e <= pe + tol, (
+                f"{name} [{s}, {e}] overlaps {pname} [{ps}, {pe}]")
+        stack.append((s, e, name))
 
 
 # -------------------------------------------- tracing on/off bit-equality
@@ -327,6 +359,7 @@ def test_tracing_bit_equal_across_grid(obs_fixture):
                 assert len(tracer) > 0 and tracer.dropped == 0
                 problems = te.validate(tracer.to_chrome())
                 assert not problems, f"{tag}: {problems}"
+                _assert_nested_or_disjoint(tracer)
 
 
 # ------------------------------- well-formedness + sum-consistency + export
@@ -350,6 +383,37 @@ def test_spans_well_formed_and_sums_consistent(obs_fixture, tmp_path):
     assert len(tracer.events(name="doorbell")) > 0
     for e in tracer.events():
         assert e["dur"] >= 0.0, e
+
+    # the untraced half of the step: one heat and one account span a
+    # batch, one refresh every REFRESH_EVERY batches with its four phases
+    # inside it, and a poll before every admit (plus the empty polls)
+    for name in ("heat", "account"):
+        assert len(tracer.events(name=name)) == n_batches, name
+        assert [e["args"]["batch"] for e in tracer.events(name=name)] == \
+            list(range(1, n_batches + 1)), name
+    refreshes = _ranker_spans(tracer, "refresh")
+    assert len(refreshes) == n_batches // REFRESH_EVERY
+    for phase in REFRESH_PHASES:
+        inside = _ranker_spans(tracer, phase)
+        assert len(inside) == len(refreshes), phase
+        for (s, e, _), (ps, pe, _) in zip(inside, refreshes):
+            assert ps < s and e < pe, phase
+    polls = tracer.events(name="poll")
+    assert sum(e["args"]["requests"] > 0 for e in polls) == n_batches
+    assert sum(e["args"]["requests"] for e in polls) == len(reqs)
+    # the wall-clock row is laminar, and each batch's dense stage starts
+    # after its lookup stall ends (both stamped where the work started)
+    _assert_nested_or_disjoint(tracer)
+    stalls = _ranker_spans(tracer, "lookup_stall")
+    denses = _ranker_spans(tracer, "dense")
+    assert len(stalls) == len(denses) == n_batches
+    for (_, stall_end, _), (dense_start, _, _) in zip(stalls, denses):
+        assert dense_start >= stall_end
+    # each poll ends before the admit it fed starts
+    admits = _ranker_spans(tracer, "admit")
+    fed = [e for e in polls if e["args"]["requests"] > 0]
+    for p, (admit_start, _, _) in zip(fed, admits):
+        assert p["ts"] + p["dur"] <= admit_start
 
     # per-WR virtual events carry the batch correlation key and nest
     # inside their batch's lookup_batch span
