@@ -83,6 +83,7 @@ class HostHashCache:
         # fetched row that has not yet served a hit (repro.prefetch).
         self.prefetched = np.zeros((num_slots,), bool)
         self.prefetch_evicted = 0  # prefetched rows evicted before any hit
+        self.insert_rounds = 0  # commit rounds run by insert (cumulative)
 
     # ------------------------------------------------------------------ read
 
@@ -135,6 +136,20 @@ class HostHashCache:
     ) -> int:
         """Batch insert under the table.cache_insert rules; returns #admitted.
 
+        The result is the table the rules give when the ids are inserted
+        one after another, each seeing every earlier id's write, but the
+        ids are committed in vectorised rounds.  Each id depends on one
+        slot of its probe window (the slot holding it, else the first
+        vacant one) or, when the window is full, on all of it.  An id
+        commits in a round when no earlier pending id's window covers a
+        slot it depends on: earlier ids only ever write inside their own
+        windows and never empty a slot, so nothing they do can change its
+        outcome.  The ids that commit in one round touch disjoint slots and
+        are written together; the rest wait for the next round.  A round
+        costs O(pending × max_probes) plus a binary search over the pending
+        ids' home slots, and no array the size of the table; the running
+        ``insert_rounds`` counts the rounds.
+
         ``prefetched=True`` marks the admitted slots for hit attribution
         (repro.prefetch); a demand insert refreshing a still-untouched
         prefetched row clears the mark — the demand path would have fetched
@@ -142,38 +157,75 @@ class HostHashCache:
         """
         if self.num_slots == 0:
             return 0
+        C, P = self.num_slots, self.max_probes
+        ids = np.asarray(ids, np.int64)
+        rows, freqs = np.asarray(rows), np.asarray(freqs)
+        live = np.flatnonzero(ids != EMPTY_KEY)
+        home = hash_slots_np(ids[live], C)
+        # Pending ids as batch positions, sorted by home slot and stably, so
+        # each home's earliest id leads its run; filtering keeps the order.
+        order = np.argsort(home, kind="stable")
+        pending, hp = live[order], home[order]
         admitted = 0
-        home = hash_slots_np(ids, self.num_slots)
-        for i in range(len(ids)):
-            id_i = int(ids[i])
-            if id_i == EMPTY_KEY:
-                continue
-            window = (home[i] + np.arange(self.max_probes)) & (self.num_slots - 1)
-            kw = self.keys[window]
-            match = np.flatnonzero(kw == id_i)
-            if len(match):
-                t = window[match[0]]
-                self.rows[t] = rows[i]
-                self.freq[t] += freqs[i]
-                self.prefetched[t] &= prefetched
-                admitted += 1
-                continue
-            if freqs[i] < admission_threshold:
-                continue
-            vacant = np.flatnonzero(kw == EMPTY_KEY)
-            if len(vacant):
-                t = window[vacant[0]]
-            else:
-                t = window[np.argmin(self.freq[window])]
-                if freqs[i] <= self.freq[t]:
-                    continue  # incumbent is at least as hot: keep it
-                if self.prefetched[t]:
-                    self.prefetch_evicted += 1  # speculation lost the slot
-            self.keys[t] = id_i
-            self.rows[t] = rows[i]
-            self.freq[t] = freqs[i]
+        while len(pending):
+            self.insert_rounds += 1
+            m = len(pending)
+            win = (hp[:, None] + np.arange(P)) & (C - 1)
+            key, f = ids[pending], freqs[pending]
+            kw = self.keys[win]
+            match, vacant = kw == key[:, None], kw == EMPTY_KEY
+            resident = match.any(axis=1)
+            has_vacant = vacant.any(axis=1)
+            cold = ~resident & (f < admission_threshold)
+            # The slot each outcome rests on; a full window rests on all of
+            # it.  A cold id only waits on an earlier copy of itself, whose
+            # window is its own, so any slot of it will do.
+            pos = np.where(resident, match.argmax(axis=1), vacant.argmax(axis=1))
+            slot = win[np.arange(m), pos]
+            one_slot = resident | has_vacant | cold
+            # The homes whose windows cover those slots span [lo, hi]
+            # (unwrapped: homes are listed again one table length below
+            # and above); the id is free when it is the earliest pending id
+            # homed there.
+            run = np.flatnonzero(np.r_[True, hp[1:] != hp[:-1]])
+            homes = np.concatenate([hp[run] - C, hp[run], hp[run] + C])
+            earliest = np.tile(pending[run], 3)
+            lo = np.searchsorted(homes, np.where(one_slot, slot, hp) - P + 1)
+            hi = np.searchsorted(
+                homes, np.where(one_slot, slot, hp + P - 1), side="right"
+            )
+            claim = pending.copy()
+            for d in range(int((hi - lo).max())):  # < 2 * P distinct homes
+                k = np.flatnonzero(lo + d < hi)
+                claim[k] = np.minimum(claim[k], earliest[lo[k] + d])
+            free = claim == pending
+            # Rule 1: refresh the resident row.
+            hit = np.flatnonzero(free & resident)
+            t = slot[hit]
+            self.rows[t] = rows[pending[hit]]
+            self.freq[t] += f[hit]
+            self.prefetched[t] &= prefetched
+            # Rules 2-3: claim the first vacancy, else the LFU victim iff
+            # strictly hotter.
+            new = np.flatnonzero(free & ~resident & ~cold)
+            t = slot[new]
+            full = ~has_vacant[new]
+            if full.any():
+                fw = win[new[full]]
+                victim = fw[np.arange(len(fw)), self.freq[fw].argmin(axis=1)]
+                t[full] = victim
+                beats = np.ones(len(new), bool)
+                beats[full] = ~(f[new[full]] <= self.freq[victim])
+                new, t = new[beats], t[beats]
+                self.prefetch_evicted += int(
+                    self.prefetched[t[full[beats]]].sum()
+                )  # speculation lost the slot
+            self.keys[t] = key[new]
+            self.rows[t] = rows[pending[new]]
+            self.freq[t] = f[new]
             self.prefetched[t] = prefetched
-            admitted += 1
+            admitted += len(hit) + len(new)
+            pending, hp = pending[~free], hp[~free]
         return admitted
 
     def decay(self, factor: float) -> None:

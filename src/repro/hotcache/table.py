@@ -176,7 +176,10 @@ def cache_insert(
 
     Returns (new_state, admitted [K] bool).  Sequential by construction
     (inserts see earlier inserts) via fori_loop — swap-in batches are small
-    (O(cache capacity), off the serving hot path).
+    (O(cache capacity), off the serving hot path).  The host mirror,
+    ``miss_path.HostHashCache.insert``, gives the same table without a loop
+    over ids: it commits, in vectorised rounds, every id that no earlier
+    pending id's probe window can reach.
     """
     thr = jnp.asarray(admission_threshold, jnp.int32)
     K = ids.shape[0]

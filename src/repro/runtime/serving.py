@@ -829,6 +829,7 @@ class FlexEMRServer:
             if tracer.enabled:
                 self._span("refresh_fetch", CAT_CACHE, t, rows=k, fresh=fresh)
                 t = time.perf_counter()
+            rounds = cache.insert_rounds
             # The planned rows ARE the chosen hot set: threshold 1 (always
             # admit); plan.admission_threshold gates runtime misses instead.
             cache.insert(ids, rows, freqs, 1.0)
@@ -840,7 +841,7 @@ class FlexEMRServer:
                 self.prefetcher.decay()
             if tracer.enabled:
                 self._span("refresh_insert", CAT_CACHE, t, rows=k,
-                           fresh=fresh)
+                           fresh=fresh, rounds=cache.insert_rounds - rounds)
         if hasattr(self.service, "set_shard_affinity"):
             t = time.perf_counter() if tracer.enabled else 0.0
             # Skew-aware dealing (§3.2 follow-on): feed the controller's

@@ -1,5 +1,7 @@
 """repro.hotcache: hash table vs dict oracle, Pallas kernels vs ref oracles,
 and the tiered miss path end-to-end on zipf-skewed traffic."""
+import copy
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -103,11 +105,15 @@ def test_insert_probe_evict_matches_dict_oracle(seed, thr):
         np.testing.assert_array_equal(vals[s], r)
     np.testing.assert_array_equal(keys.astype(np.int64), want_keys)
 
-    # the numpy host mirror replays the same sequence to the same table
+    # the numpy host mirror replays the same sequence to the same table,
+    # one id a call and the whole sequence in one call
     host = HostHashCache(C, D, max_probes=P)
     for i in range(n_ops):
         host.insert(ids[i : i + 1], rows[i : i + 1], freqs[i : i + 1], thr)
     np.testing.assert_array_equal(host.keys, want_keys)
+    bulk = HostHashCache(C, D, max_probes=P)
+    bulk.insert(ids, rows, freqs, thr)
+    np.testing.assert_array_equal(bulk.keys, want_keys)
 
     # every id the table claims to hold is returned exactly on lookup
     probe_rows, hit = cache_lookup(state, jnp.asarray(ids), max_probes=P)
@@ -330,6 +336,136 @@ def test_host_cache_insert_collision_and_full_table(rng):
         np.array([EMPTY_KEY], np.int64), rows[:1], np.array([50.0]), 1.0
     )
     assert n == 0 and cache.occupancy == P
+
+
+def _sequential_insert(
+    cache, ids, rows, freqs, admission_threshold=1.0, prefetched=False
+):
+    """The row-at-a-time HostHashCache.insert: the oracle for the rounds."""
+    if cache.num_slots == 0:
+        return 0
+    admitted = 0
+    home = hash_slots_np(ids, cache.num_slots)
+    for i in range(len(ids)):
+        id_i = int(ids[i])
+        if id_i == EMPTY_KEY:
+            continue
+        window = (home[i] + np.arange(cache.max_probes)) & (cache.num_slots - 1)
+        kw = cache.keys[window]
+        match = np.flatnonzero(kw == id_i)
+        if len(match):
+            t = window[match[0]]
+            cache.rows[t] = rows[i]
+            cache.freq[t] += freqs[i]
+            cache.prefetched[t] &= prefetched
+            admitted += 1
+            continue
+        if freqs[i] < admission_threshold:
+            continue
+        vacant = np.flatnonzero(kw == EMPTY_KEY)
+        if len(vacant):
+            t = window[vacant[0]]
+        else:
+            t = window[np.argmin(cache.freq[window])]
+            if freqs[i] <= cache.freq[t]:
+                continue  # incumbent is at least as hot: keep it
+            if cache.prefetched[t]:
+                cache.prefetch_evicted += 1  # speculation lost the slot
+        cache.keys[t] = id_i
+        cache.rows[t] = rows[i]
+        cache.freq[t] = freqs[i]
+        cache.prefetched[t] = prefetched
+        admitted += 1
+    return admitted
+
+
+def _random_calls(rng, thr, prefetched=False, n_calls=4):
+    """Calls of random ids with duplicates and EMPTY_KEY entries into a
+    64-slot table, so windows fill and evict."""
+    calls = []
+    for _ in range(n_calls):
+        ids = rng.integers(0, 160, 120).astype(np.int64)
+        ids[rng.random(120) < 0.08] = EMPTY_KEY
+        freqs = rng.integers(1, 12, 120).astype(np.float64)
+        calls.append((ids, freqs, thr, prefetched))
+    return calls
+
+
+def _case_random(thr):
+    def build(rng):
+        return HostHashCache(64, 8, max_probes=4), _random_calls(rng, thr)
+    return build
+
+
+def _case_prefetched(flag):
+    def build(rng):
+        # the table already holds prefetched rows before the checked calls
+        cache = HostHashCache(64, 8, max_probes=4)
+        ids = rng.permutation(300)[:48].astype(np.int64)
+        cache.insert(ids, rng.normal(size=(48, 8)).astype(np.float32),
+                     rng.integers(1, 6, 48).astype(np.float64), 1.0,
+                     prefetched=True)
+        assert cache.prefetched.sum() > 0
+        return cache, _random_calls(rng, 1.0, prefetched=flag)
+    return build
+
+
+def _case_colliding(rng):
+    # ids sharing one home slot fill their window; equal freqs make every
+    # victim choice a tie and every challenger at the min freq a loser
+    C, P = 64, 4
+    ids = _colliding_ids(C, P, 12)
+    calls = []
+    for _ in range(3):
+        sel = rng.permutation(np.r_[ids, ids[:4]])
+        freqs = rng.choice([2.0, 2.0, 3.0, 5.0], len(sel))
+        calls.append((sel, freqs, 1.0, bool(rng.random() < 0.5)))
+    return HostHashCache(C, 8, max_probes=P), calls
+
+
+def _case_refresh(rng):
+    # the refresh's shape: unique hot ids, half the table's slots a call,
+    # each call overlapping the last, until eviction engages
+    n, C = 8192, 16384
+    universe = rng.permutation(200_000)[: 4 * n].astype(np.int64)
+    calls = []
+    for c in range(5):
+        ids = rng.permutation(universe[c * n // 3 : c * n // 3 + n])
+        calls.append((ids, rng.random(n) * 50.0, 1.0, False))
+    return HostHashCache(C, 8), calls
+
+
+@pytest.mark.parametrize(
+    "build",
+    [_case_random(1.0), _case_random(3.0), _case_random(8.0),
+     _case_prefetched(True), _case_prefetched(False), _case_colliding,
+     _case_refresh],
+    ids=["random-thr1", "random-thr3", "random-thr8", "prefetched-true",
+         "prefetched-false", "colliding-ties", "refresh-shaped"],
+)
+def test_bulk_insert_matches_sequential_oracle(build):
+    """HostHashCache.insert commits in rounds what the row loop does one id
+    at a time: the same table, counters and admitted count, bit for bit."""
+    rng = np.random.default_rng(15)
+    cache, calls = build(rng)
+    oracle = copy.deepcopy(cache)
+    evicted = 0
+    for ids, freqs, thr, prefetched in calls:
+        rows = rng.normal(size=(len(ids), cache.rows.shape[1])).astype(
+            np.float32)
+        rounds = cache.insert_rounds
+        before = cache.keys[cache.keys != EMPTY_KEY]
+        want = _sequential_insert(oracle, ids, rows, freqs, thr, prefetched)
+        assert cache.insert(ids, rows, freqs, thr, prefetched) == want
+        assert cache.insert_rounds > rounds
+        for name in ("keys", "rows", "freq", "prefetched"):
+            np.testing.assert_array_equal(
+                getattr(cache, name), getattr(oracle, name), err_msg=name)
+        assert cache.prefetch_evicted == oracle.prefetch_evicted
+        evicted += len(np.setdiff1d(before, cache.keys))
+        cache.decay(0.5)
+        oracle.decay(0.5)
+    assert evicted > 0  # the LFU eviction path ran
 
 
 def test_tiered_refresh_insert_decay_stress(rng):

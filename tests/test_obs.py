@@ -464,6 +464,35 @@ def test_spans_well_formed_and_sums_consistent(obs_fixture, tmp_path):
         te.load(str(tmp_path / "missing.json"))
 
 
+def test_refresh_insert_span_counts_rounds(obs_fixture):
+    """A traced cache-plan application records the insert's commit rounds
+    on ``refresh_insert``: the advance of ``HostHashCache.insert_rounds``."""
+    cfg, params, tables, reqs = obs_fixture
+    tracer = Tracer()
+    server = FlexEMRServer(
+        cfg, params, tables, controller=_controller(cfg),
+        cache_refresh_every=REFRESH_EVERY,
+        batcher=BucketBatcher(buckets=(8,), max_wait=0.001),
+        tracer=tracer, registry=MetricsRegistry(),
+    )
+    try:
+        for r in reqs:
+            server.submit(r)
+        while server.step() is not None or \
+                server.metrics.requests < len(reqs):
+            pass
+        cache = server._tiered.cache
+        rounds = cache.insert_rounds
+        server._apply_cache_plan(server.metrics.batches)
+        assert server._tiered.cache is cache
+        args = tracer.events(name="refresh_insert")[-1]["args"]
+        assert args["rounds"] >= 1
+        assert cache.insert_rounds - rounds == args["rounds"]
+        assert args["rows"] >= args["fresh"]
+    finally:
+        server.close()
+
+
 def test_trace_export_flags_malformed(tmp_path):
     te = _trace_export()
     bad = tmp_path / "bad.json"
