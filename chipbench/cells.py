@@ -2,24 +2,29 @@
 
 A cell is one entry of ``workloads`` in ``BENCHMARK.json``: a configuration
 (``chipbench/configs/<config>.json``) under a traffic mix
-(``chipbench/traffic/<traffic>.json``).  Per-layer metrics are readers in
-``chipbench/metrics/<name>.py``.  Nothing here knows any cell by name, so a
-later cell, mix or metric is a new file and an entry in ``BENCHMARK.json``.
+(``chipbench/traffic/<traffic>.json``).  A configuration's model head is
+``chipbench/heads/<arch>.py``; per-layer metrics are readers in
+``chipbench/metrics/<name>.py``.  Nothing here knows any cell, head or
+metric by name, so a later one is a new file and an entry in
+``BENCHMARK.json``.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
 import numbers
 import pathlib
+import re
 
 BENCH_DIR = pathlib.Path(__file__).resolve().parent
 ROOT = BENCH_DIR.parent
 
-CONFIG_KEYS = ("name", "source", "arch", "embed_dim", "n_dense", "bottom_mlp",
-               "top_mlp", "interaction_itself", "row_cut", "row_cut_above",
-               "tables", "serve", "correctness", "reduced", "assumed")
+# Every head's keys; a head names its own in ``KEYS``.
+CONFIG_KEYS = ("name", "source", "arch", "embed_dim", "n_dense", "row_cut",
+               "row_cut_above", "tables", "serve", "correctness", "reduced",
+               "assumed")
 TRAFFIC_KEYS = ("source", "key_law", "arrivals", "rate_rps", "warm_s",
                 "tail_s")
 KEY_LAWS = {"zipf": ("alpha",), "uniform": ()}  # law -> its own keys
@@ -61,18 +66,49 @@ def _whole(v) -> bool:
     return isinstance(v, int) and not isinstance(v, bool)
 
 
-def load_config(path: pathlib.Path) -> dict:
-    """A configuration file, checked: every key present, the table list
-    matching the stated row cut (``1/row_cut`` of each table over
-    ``row_cut_above`` declared rows, the others whole), ``reduced`` naming
-    exactly the tables cut, and only a DLRM head (all this harness
-    drives)."""
+def _load_module(path: pathlib.Path, name: str):
+    if not path.is_file():
+        return None
+    spec = importlib.util.spec_from_file_location(name, path)
+    if spec is None or spec.loader is None:
+        return None
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@functools.lru_cache(maxsize=None)
+def load_head(arch: str, root: pathlib.Path = ROOT):
+    """The module ``<root>/chipbench/heads/<arch>.py``: its ``KEYS``,
+    ``check``, ``program_config``, ``make_weights``, ``reference_scores``,
+    ``dense_flops_per_request`` and ``top_in``.  Loaded once per process,
+    so its compiled functions are too."""
+    if not isinstance(arch, str) or not re.fullmatch(r"[A-Za-z0-9_]+", arch):
+        raise SpecError(f"arch {arch!r} is not a head's name")
+    path = pathlib.Path(root) / "chipbench" / "heads" / f"{arch}.py"
+    mod = _load_module(path, f"chipbench_head_{arch}")
+    if mod is None:
+        raise SpecError(f"arch {arch!r} has no head in chipbench/heads")
+    return mod
+
+
+def load_config(path: pathlib.Path, root: pathlib.Path = ROOT) -> dict:
+    """A configuration file, checked: every key present, its own head's
+    too, the table list matching the stated row cut (``1/row_cut`` of each
+    table over ``row_cut_above`` declared rows, the others whole),
+    ``reduced`` naming exactly the tables cut, and the head's own rules."""
     cfg = json.loads(pathlib.Path(path).read_text())
     missing = [k for k in CONFIG_KEYS if k not in cfg]
     if missing:
         raise SpecError(f"{path}: missing {missing}")
-    if cfg["arch"] != "dlrm":
-        raise SpecError(f"{path}: arch {cfg['arch']!r} is not served")
+    try:
+        head = load_head(cfg["arch"], pathlib.Path(root))
+    except SpecError as exc:
+        raise SpecError(f"{path}: {exc}") from None
+    missing = [k for k in head.KEYS if k not in cfg]
+    if missing:
+        raise SpecError(f"{path}: missing {missing}, keys of head "
+                        f"{cfg['arch']!r}")
     cut, above = cfg["row_cut"], cfg["row_cut_above"]
     if not _whole(cut) or cut < 1 or not _whole(above) or above < 0:
         raise SpecError(f"{path}: row_cut and row_cut_above must be whole "
@@ -95,10 +131,10 @@ def load_config(path: pathlib.Path) -> dict:
     if listed != cut_tables:
         raise SpecError(f"{path}: reduced must name the tables the cut "
                         f"changes, {sorted(cut_tables)}, not {sorted(listed)}")
-    if cfg["bottom_mlp"][-1] != cfg["embed_dim"]:
-        raise SpecError(f"{path}: bottom MLP must end at embed_dim")
-    if cfg["top_mlp"][-1] != 1:
-        raise SpecError(f"{path}: top MLP must end in one score")
+    try:
+        head.check(cfg)
+    except SpecError as exc:
+        raise SpecError(f"{path}: {exc}") from None
     return cfg
 
 
@@ -142,8 +178,9 @@ def resolve(name: str, root: pathlib.Path = ROOT) -> Cell:
         raise SpecError(f"no workload {name!r} in BENCHMARK.json")
     w = cells[name]
     configs = {c["name"]: c for c in bench["configs"]}
-    cfg = load_config(pathlib.Path(root) / configs[w["config"]]["file"])
-    mix = load_traffic(BENCH_DIR / "traffic" / f"{w['traffic']}.json")
+    root = pathlib.Path(root)
+    cfg = load_config(root / configs[w["config"]]["file"], root)
+    mix = load_traffic(root / "chipbench" / "traffic" / f"{w['traffic']}.json")
 
     def applies(m: Metric) -> bool:
         return m.workloads is None or name in m.workloads
@@ -160,10 +197,7 @@ def resolve(name: str, root: pathlib.Path = ROOT) -> Cell:
 def load_reader(metric: str):
     """The ``read(window)`` function of ``chipbench/metrics/<metric>.py``."""
     path = BENCH_DIR / "metrics" / f"{metric}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"chipbench_metric_{metric.replace('.', '_')}", path)
-    if spec is None or spec.loader is None:
+    mod = _load_module(path, f"chipbench_metric_{metric.replace('.', '_')}")
+    if mod is None:
         raise SpecError(f"no reader for metric {metric!r} at {path}")
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
     return mod.read

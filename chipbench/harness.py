@@ -2,7 +2,8 @@
 
 The served path under test is ``repro.runtime.serving.FlexEMRServer``,
 built as ``repro.launch.serve.build`` builds it, from the configuration
-file's sizes and serve options, with weights the benchmark makes.  Traffic
+file's sizes and serve options, as its head (``chipbench/heads/<arch>.py``)
+describes the model to the program, with weights the head makes.  Traffic
 is made whole in set-up and driven open-loop (``driver.drive``).  After the
 window the plain reference (``model.reference_scores``, weights made again
 from the seed) scores every request that arrived in the window.
@@ -46,19 +47,6 @@ def use_compile_cache() -> pathlib.Path:
     return path
 
 
-def recsys_config(config: dict):
-    from repro.core.sharding import TableSpec
-    from repro.models.recsys import RecsysConfig
-
-    specs = tuple(TableSpec(t["name"], t["rows"], nnz=t["nnz"])
-                  for t in config["tables"])
-    return RecsysConfig(
-        name=config["name"], arch="dlrm", tables=specs,
-        embed_dim=config["embed_dim"], n_dense=config["n_dense"],
-        bottom_mlp=tuple(config["bottom_mlp"]),
-        mlp=tuple(config["top_mlp"][:-1]), mode="hierarchical")
-
-
 def build_server(config: dict, params: dict, tracer=None):
     """The server ``repro.launch.serve.build`` makes, from the file."""
     from repro.core.adaptive_cache import AdaptiveCacheController, MemoryModel
@@ -68,7 +56,7 @@ def build_server(config: dict, params: dict, tracer=None):
     from repro.runtime.serving import FlexEMRServer
 
     s = config["serve"]
-    rc = recsys_config(config)
+    rc = model.head(config).program_config(config)
     tables = make_fused_tables(rc.tables, rc.embed_dim, s["num_servers"])
     if tables.total_rows != model.total_rows(config):
         raise ValueError(f"fused table has {tables.total_rows} rows, the "
@@ -87,13 +75,13 @@ def build_server(config: dict, params: dict, tracer=None):
         tracer=tracer, registry=get_registry(), slo=slo)
 
 
-def host_params(config: dict, weights) -> dict:
+def host_params(config: dict, weights: dict) -> dict:
     """The served path's params: the table copied to the host as
-    ``[rows, dim]``, the MLPs left on the device."""
-    table, bottom, top = weights
-    host = np.asarray(table).reshape(model.total_rows(config),
-                                     config["embed_dim"])
-    return {"emb": {"table": host}, "bottom": bottom, "top": top}
+    ``[rows, dim]``, every other entry (the dense stage's weights) left as
+    the head made it, on the device."""
+    host = np.asarray(weights["emb"]["table"]).reshape(
+        model.total_rows(config), config["embed_dim"])
+    return {**weights, "emb": {**weights["emb"], "table": host}}
 
 
 def snapshot(server) -> dict:

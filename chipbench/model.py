@@ -1,32 +1,39 @@
-"""The benchmark's own DLRM: seeded weights, the plain reference, FLOPs.
+"""The benchmark's own model: what every head shares, and the hand-off to
+the configuration's head.
 
-Nothing here imports the program.  The weights are made on the device in
-one jitted call from the seed, in the layout the served path takes
-(``{"emb": {"table"}, "bottom": {w0, b0, ...}, "top": {...}}``).  The
+Nothing here imports the program.  A model head is a file of its own,
+``chipbench/heads/<arch>.py``, found by the configuration's ``arch``
+(``cells.load_head``); ``make_weights``, ``reference_scores``,
+``dense_flops_per_request`` and ``top_in`` below hand the call to it.  A
+head makes its weights on the device in one jitted call from the seed, in
+the layout the served path takes (``{"emb": {"table"}, ...}``).  The
 embedding table is made line-packed, ``[rows * dim / 128, 128]``: a line
 holds ``128 // dim`` consecutive rows, so its row-major host copy reshaped
 to ``[rows, dim]`` is the table, and the reference gathers whole lines
-without a lane-padded copy of the table.
+without a lane-padded copy of the table (``pool``).
 
-The reference is DLRM as published (Naumov et al. 2019,
-facebookresearch/dlrm): sum-pooled bags, a bottom MLP with ReLU on every
-layer, pairwise dot interaction over the bottom vector and the pooled
-fields (self-dots kept when the configuration says so), the interaction
-concatenated with the bottom vector, and a top MLP with ReLU on all but
-its last layer, whose output is the score.  Its matmuls run at float32
+Every head's reference pools with ``pool`` and multiplies with
+``matmul``, so that all heads round alike.  Its matmuls run at float32
 (``highest``); ``high`` computes each product from bfloat16 halves, three
 passes, as a TPU does at that precision: that is the control.
 """
 from __future__ import annotations
 
-import functools
 import math
+import pathlib
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from chipbench import cells
+
 LANES = 128
+
+# The MLPs come from this fixed seed, the table from the run's: the served
+# dense stage compiles its weights in as constants, so MLPs that changed
+# with the seed would make every run compile it again in set-up.
+DENSE_SEED = 0
 
 
 def total_rows(config: dict) -> int:
@@ -61,42 +68,6 @@ def _mlp_params(key, sizes) -> dict:
     return out
 
 
-def top_in(config: dict) -> int:
-    v = len(config["tables"]) + 1
-    pairs = v * (v + 1) // 2 if config["interaction_itself"] else v * (v - 1) // 2
-    return pairs + config["embed_dim"]
-
-
-# The MLPs come from this fixed seed, the table from the run's: the served
-# dense stage compiles its weights in as constants, so MLPs that changed
-# with the seed would make every run compile it again in set-up.
-DENSE_SEED = 0
-
-
-@functools.lru_cache(maxsize=None)
-def _weights_fn(lines: int, dim: int, n_dense: int, bottom: tuple,
-                top_sizes: tuple):
-    def make(k_emb, k_dense):
-        k_bot, k_top = jax.random.split(k_dense)
-        a = 1.0 / math.sqrt(dim)
-        table = jax.random.uniform(k_emb, (lines, LANES), jnp.float32, -a, a)
-        return (table, _mlp_params(k_bot, (n_dense,) + bottom),
-                _mlp_params(k_top, top_sizes))
-    return jax.jit(make)
-
-
-def make_weights(config: dict, seed: int):
-    """(line-packed table, bottom MLP, top MLP), on the default device, in
-    one jitted call: the table from ``seed``, the MLPs from
-    ``DENSE_SEED``."""
-    dim = config["embed_dim"]
-    lines = total_rows(config) * dim // LANES
-    fn = _weights_fn(lines, dim, config["n_dense"],
-                     tuple(config["bottom_mlp"]),
-                     (top_in(config),) + tuple(config["top_mlp"]))
-    return fn(base_key(seed), base_key(DENSE_SEED))
-
-
 def _split_bf16(x):
     # reduce_precision, not a round trip through bfloat16: XLA may drop
     # a convert pair as excess precision, and did on the TPU.
@@ -129,49 +100,37 @@ def _mlp(params: dict, x, precision: str, final_act: bool):
     return x
 
 
-@functools.lru_cache(maxsize=None)
-def _forward_fn(dim: int, itself: bool, precision: str):
+def pool(table, rows, mask, dim: int):
+    """Sum-pooled bags, ``[B, F, dim]``, from the line-packed ``table``:
+    ``rows`` ``[B, F, K]`` are fused-table rows, ``mask`` marks the ids
+    each bag holds."""
     per = LANES // dim
-
-    def fwd(table, bottom, top, rows, mask, dense):
-        b, f, k = rows.shape
-        lines = jnp.take(table, rows // per, axis=0)  # [B, F, K, 128]
-        lines = lines.reshape(b, f, k, per, dim)
-        pick = (rows % per)[..., None, None]
-        vals = jnp.take_along_axis(lines, pick, axis=3)[..., 0, :]
-        pooled = jnp.sum(jnp.where(mask[..., None], vals, 0.0), axis=2)
-        bot = _mlp(bottom, dense, precision, final_act=True)
-        vecs = jnp.concatenate([bot[:, None, :], pooled], axis=1)
-        gram = matmul(vecs, jnp.swapaxes(vecs, 1, 2), precision)
-        iu, ju = np.triu_indices(f + 1, 0 if itself else 1)
-        inter = gram[:, iu, ju]
-        return _mlp(top, jnp.concatenate([inter, bot], axis=-1), precision,
-                    final_act=False)[:, 0]
-    return jax.jit(fwd)
+    b, f, k = rows.shape
+    lines = jnp.take(table, rows // per, axis=0)  # [B, F, K, 128]
+    lines = lines.reshape(b, f, k, per, dim)
+    pick = (rows % per)[..., None, None]
+    vals = jnp.take_along_axis(lines, pick, axis=3)[..., 0, :]
+    return jnp.sum(jnp.where(mask[..., None], vals, 0.0), axis=2)
 
 
-def reference_scores(config: dict, weights, indices: np.ndarray,
-                     mask: np.ndarray, dense: np.ndarray,
-                     precision: str = "highest", block: int = 4096
-                     ) -> np.ndarray:
-    """Scores of the plain DLRM for every request, ``block`` at a time."""
-    table, bottom, top = weights
-    fn = _forward_fn(config["embed_dim"], bool(config["interaction_itself"]),
-                     precision)
+def in_blocks(fn, config: dict, indices: np.ndarray, mask: np.ndarray,
+              dense: np.ndarray, block: int) -> np.ndarray:
+    """``fn(rows, mask, dense)`` over every request, ``block`` at a time,
+    the last block padded (one shape, one compile); ``rows`` are the
+    per-field ids offset into the fused table."""
     rows = indices.astype(np.int64) + field_offsets(config)[None, :, None]
     rows = rows.astype(np.int32)
     n = len(rows)
     out = np.empty(n, np.float32)
     for s in range(0, n, block):
         e = min(n, s + block)
-        pad = block - (e - s)  # one shape, one compile
+        pad = block - (e - s)
 
         def blk(x):
             x = x[s:e]
             return np.concatenate([x, np.zeros((pad,) + x.shape[1:], x.dtype)]
                                   ) if pad else x
-        out[s:e] = np.asarray(fn(table, bottom, top, blk(rows), blk(mask),
-                                 blk(dense)))[:e - s]
+        out[s:e] = np.asarray(fn(blk(rows), blk(mask), blk(dense)))[:e - s]
     return out
 
 
@@ -181,13 +140,33 @@ def score_gap(served: np.ndarray, ref: np.ndarray) -> float:
     return float(np.max(np.abs(served - ref))) / max(rms, 1e-30)
 
 
-def dense_flops_per_request(config: dict) -> int:
-    """Operations the dense stage needs per request (2 per multiply-add):
-    the two MLPs and the pairwise dots the interaction keeps."""
-    def mlp(sizes):
-        return sum(2 * a * b for a, b in zip(sizes[:-1], sizes[1:]))
-    v = len(config["tables"]) + 1
-    pairs = v * (v + 1) // 2 if config["interaction_itself"] else v * (v - 1) // 2
-    return (mlp([config["n_dense"]] + list(config["bottom_mlp"]))
-            + 2 * pairs * config["embed_dim"]
-            + mlp([top_in(config)] + list(config["top_mlp"])))
+def head(config: dict, root: pathlib.Path = cells.ROOT):
+    """The module of the configuration's head (``cells.load_head``)."""
+    return cells.load_head(config["arch"], root)
+
+
+def make_weights(config: dict, seed: int, root: pathlib.Path = cells.ROOT
+                 ) -> dict:
+    """The served layout's weights, on the default device, from ``seed``."""
+    return head(config, root).make_weights(config, seed)
+
+
+def reference_scores(config: dict, weights: dict, indices: np.ndarray,
+                     mask: np.ndarray, dense: np.ndarray,
+                     precision: str = "highest", block: int = 4096,
+                     root: pathlib.Path = cells.ROOT) -> np.ndarray:
+    """Scores of the plain reference for every request, ``block`` at a
+    time."""
+    return head(config, root).reference_scores(
+        config, weights, indices, mask, dense, precision, block)
+
+
+def dense_flops_per_request(config: dict,
+                            root: pathlib.Path = cells.ROOT) -> int:
+    """Operations the dense stage needs per request (2 per multiply-add)."""
+    return head(config, root).dense_flops_per_request(config)
+
+
+def top_in(config: dict, root: pathlib.Path = cells.ROOT) -> int:
+    """Width of the head's top MLP input."""
+    return head(config, root).top_in(config)

@@ -15,11 +15,27 @@ def _write(tmp_path, obj, name="f.json"):
     return p
 
 
-@pytest.mark.parametrize("key", ["source", "reduced", "assumed"])
+@pytest.mark.parametrize("key", ["source", "reduced", "assumed",
+                                 # keys of the config's head (dlrm)
+                                 "bottom_mlp", "interaction_itself"])
 def test_config_without_provenance_refused(tmp_path, key):
     cfg = json.loads(CONFIG.read_text())
     del cfg[key]
     with pytest.raises(cells.SpecError, match=key):
+        cells.load_config(_write(tmp_path, cfg))
+
+
+@pytest.mark.parametrize("key,value,match", [
+    ("arch", "dcnv2", "has no head in chipbench/heads"),
+    ("arch", "../heads/dlrm", "not a head's name"),
+    ("bottom_mlp", [512, 256, 32], "embed_dim"),
+    ("top_mlp", [512, 256, 2], "one score"),
+])
+def test_config_head_refused(tmp_path, key, value, match):
+    """An arch with no head file, and the head's own rules."""
+    cfg = json.loads(CONFIG.read_text())
+    cfg[key] = value
+    with pytest.raises(cells.SpecError, match=match):
         cells.load_config(_write(tmp_path, cfg))
 
 
